@@ -48,7 +48,9 @@ def main() -> None:
         raise SystemExit(2)
 
     from benchmarks import common
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     # One emit-record sidecar per invocation (benchmarks/common.emit
     # appends; without the reset, records would accumulate across runs).
     common.reset_records()

@@ -20,6 +20,7 @@ from repro.core import channel as CH
 from repro.core import transport as T
 from repro.data.tokens import TokenStream
 from repro.launch import steps as S
+from repro.launch.mesh import make_mesh
 from repro.models import registry as R
 from repro.optim.sgd import sgd as make_sgd
 
@@ -39,7 +40,7 @@ def main():
         d_ff=2048, vocab_size=32000)
     n_dev = len(jax.devices())
     dshape = (n_dev // 2, 2) if n_dev >= 4 else (n_dev, 1)
-    mesh = jax.make_mesh(dshape, ("data", "model"))
+    mesh = make_mesh(dshape, ("data", "model"))
 
     tcfg = T.TransportConfig(mode="approx",
                              channel=CH.ChannelConfig(snr_db=args.snr_db))

@@ -1,9 +1,1 @@
-"""Reproduction of "Approximate Wireless Communication for Federated Learning".
-
-Importing the package installs :mod:`repro.compat`, which backfills the
-modern jax sharding API (``jax.shard_map``, ``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``jax.lax.axis_size``) on older jax
-releases — a no-op on current jax.
-"""
-
-from repro import compat as _compat  # noqa: F401  (side-effect import)
+"""Reproduction of "Approximate Wireless Communication for Federated Learning"."""

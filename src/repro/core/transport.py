@@ -860,19 +860,35 @@ def _bucketed_adaptive_aggregate(x, keys, cfgs, mode_np, snr_vec, weights,
     return total, stats
 
 
+def _vary_like(tree, ref):
+    """Mark ``tree``'s leaves as varying over every manual mesh axis ``ref``
+    varies over. A no-op outside ``shard_map``; inside it, the branches of
+    one ``lax.switch`` then agree even where a mode's stat is a constant
+    (uncoded and ECRT rows count no bit errors)."""
+    vma = jax.typeof(ref).vma
+
+    def cast(leaf):
+        missing = tuple(sorted(vma - jax.typeof(leaf).vma))
+        return jax.lax.pcast(leaf, missing, to="varying") if missing else leaf
+
+    return jax.tree.map(cast, tree)
+
+
 def _select_adaptive(x, keys, cfgs, mode_idx, snr_vec):
     """Per-client ``lax.switch`` over the table, vmapped over clients: one
     fused XLA program, but the switch lowers to a select over all branches
     (every client pays every mode's FLOPs)."""
     if snr_vec is None:
         branches = [
-            lambda xc, kc, cfg=cfg: transmit_flat(xc, kc, cfg) for cfg in cfgs
+            lambda xc, kc, cfg=cfg: _vary_like(transmit_flat(xc, kc, cfg), xc)
+            for cfg in cfgs
         ]
         return jax.vmap(
             lambda xc, kc, m: jax.lax.switch(m, branches, xc, kc)
         )(x, keys, mode_idx)
     branches = [
-        lambda xc, kc, s, cfg=cfg: transmit_flat(xc, kc, cfg, snr_db=s)
+        lambda xc, kc, s, cfg=cfg: _vary_like(
+            transmit_flat(xc, kc, cfg, snr_db=s), xc)
         for cfg in cfgs
     ]
     return jax.vmap(

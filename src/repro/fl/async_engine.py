@@ -847,6 +847,7 @@ class AsyncRoundEngine(engine_lib.RoundEngine):
                     f"(version {version}/{self.n_rounds})")
 
         self.params, self.aux, self._key = params, aux, key
+        res.params = params
         res.wall_s = time.time() - t0  # lint: ignore[determinism]
         res.final_accuracy = res.accuracy[-1]
         self._finish_run(res)

@@ -126,6 +126,8 @@ class FLResult:
     # event clock and leaves it empty, keeping its results bit-comparable
     # to pre-async runs.
     event_s: list = dataclasses.field(default_factory=list)
+    # The global model after the last round (a params pytree).
+    params: object = None
 
 
 def resolve_scenario(scenario, transport_cfg):
@@ -1193,6 +1195,7 @@ class RoundEngine:
                 if self.ledger is not None:
                     self.ledger.write_eval(r, acc, cum_air)
         self.params, self.aux, self._key = params, aux, key
+        res.params = params
         res.wall_s = time.time() - t0  # lint: ignore[determinism]
         res.final_accuracy = res.accuracy[-1]
         self._finish_run(res)

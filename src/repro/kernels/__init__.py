@@ -3,8 +3,8 @@
 ``approx_channel.py`` — fused PHY pipeline (bitcast -> interleave -> Gray-QAM
 -> Rayleigh/AWGN via counter RNG -> closed-form ML demod -> bit clamp) with
 explicit BlockSpec VMEM tiling; ``ops.py`` jit'd wrappers; ``ref.py`` the
-pure-jnp oracle (bit-exact, shared tile math). Validated interpret=True on
-CPU; compiled pallas_call on real TPUs.
+pure-jnp oracle (bit-exact, shared tile math). Compiled by Mosaic on TPU;
+run by the Pallas interpreter on CPU for tests.
 """
 
 from repro.kernels.ops import (

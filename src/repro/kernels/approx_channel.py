@@ -16,18 +16,27 @@ randomness is streamed from HBM. On real TPUs ``pltpu.prng_random_bits``
 could replace the hash; we keep the hash so interpret-mode CPU validation is
 bit-exact against the oracle.
 
-Tiling: a ``(clients, tiles)`` grid, each tile ``block_words`` float32 words
-(default 1024 = 8 sublanes x 128 lanes of f32); the single-client entry point
-is the C=1 view. Each tile expands to (32/k, block_words)
-symbols in VMEM — at QPSK that is 16 x 1024 x 4 B x ~6 live arrays ~ 400 KiB,
-comfortably inside the ~16 MiB v5e VMEM budget; the MXU is not used (this is
-a VPU/bit-op kernel). The symbol interleaver is block-local (row/column
-within the tile), matching one PHY frame per tile.
+Tiling: the ``(C, N)`` payload is viewed as ``(C, N / 128, 128)`` and cut
+into ``(block_words / 128, 128)`` tiles (default 1024 words = 8 sublanes x
+128 lanes, one f32 vreg tile) over a ``(tiles, clients)`` grid; the
+single-client entry point is the C=1 view. Each tile expands to
+``(32/k, 8, 128)`` symbols in VMEM — at QPSK that is 16 x 1024 x 4 B x ~6
+live arrays ~ 400 KiB, comfortably inside the ~16 MiB v5e VMEM budget; the
+MXU is not used (this is a VPU/bit-op kernel). The symbol interleaver is
+block-local (row/column within the tile), matching one PHY frame per tile.
 
-The multi-client uplink (``approx_channel_batch_pallas``) runs a 2-D
-``(clients, tiles)`` grid over a ``(C, N)`` payload matrix with per-client
-seed/noise/gain scalars — one fused launch for the whole cohort, each row
-bit-identical to the single-client kernel with that client's seed.
+Words enter and leave the kernel as uint32 on both wires: the bf16 wire is
+widened and narrowed by XLA around the launch, so Mosaic sees 32-bit
+vectors only (the bf16 wire then moves 4 B per word through the kernel, as
+the f32 wire does).
+
+Per-client seed / noise / gain (and aggregation weight) are scalar-prefetch
+operands in SMEM, indexed by the client grid coordinate. Bit-error counters
+are lane-dense: one ``(rows, 128)`` int32 block per tile holds every
+client's count for that tile (client ``c`` at flat position ``c``), so the
+counter output is ``tiles x C`` words in HBM and never touches SMEM. The
+tile axis is ``"parallel"``; the client axis is ``"arbitrary"`` because the
+counter block (and the fused kernel's accumulator) is revisited across it.
 """
 
 from __future__ import annotations
@@ -37,6 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
 
@@ -61,6 +71,7 @@ def approx_channel_pallas(
     clamp_mask: int = 0xBFFFFFFF,
     block_words: int = 1024,
     word_bits: int = 32,
+    valid_words: int | None = None,
     interpret: bool = True,
 ):
     """Fused PHY pipeline. x: (N,) f32 (or bf16 with word_bits=16),
@@ -80,217 +91,185 @@ def approx_channel_pallas(
         clamp_mask=clamp_mask,
         block_words=block_words,
         word_bits=word_bits,
+        valid_words=valid_words,
         interpret=interpret,
     )
     return x_hat[0], errs[0]
 
 
-def _batch_tile_body(
-    tile,
-    seed_ref,
-    noise_ref,
-    gain_ref,
-    x_ref,
-    out_ref,
-    err_ref,
-    *,
-    bits_per_symbol: int,
-    fading: str,
-    fade_block: int,
-    clamp_mask: int,
-    block_words: int,
-    word_bits: int,
-):
-    """Per-(client, tile) body. The symbol counter restarts per client and the
-    RNG is keyed by the client's own seed, so each grid row reproduces the
-    single-client kernel's stream bit-for-bit. ``tile`` is ``program_id(1)``,
-    hoisted to the caller: the masked grid stages this body inside a
-    ``pl.when`` branch, where a ``program_id`` call would not resolve under
-    the interpret-mode evaluator."""
+def _phy_tile(tile, client, seed_ref, noise_ref, gain_ref, x_ref, *,
+              bits_per_symbol: int, fading: str, fade_block: int,
+              clamp_mask: int, block_words: int, word_bits: int):
+    """``(u, u_hat)`` words of one (client, tile): sent and received-and-
+    clamped. The symbol counter restarts per client and the RNG is keyed by
+    the client's own seed, so each client reproduces the single-client
+    kernel's stream bit-for-bit."""
     s_per_word = word_bits // bits_per_symbol
-    base_sym = tile.astype(_U32) * _U32(block_words * s_per_word)
-
-    x = x_ref[0]
-    if word_bits == 16:
-        u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(_U32)
-    else:
-        u = jax.lax.bitcast_convert_type(x, _U32)
+    u = x_ref[...]
     u_hat = _ref.channel_tile(
         u,
-        seed_ref[0],
-        base_sym,
-        noise_ref[0],
-        gain_ref[0],
+        seed_ref[client],
+        tile * (block_words * s_per_word),
+        noise_ref[client],
+        gain_ref[client],
         bits_per_symbol=bits_per_symbol,
         fading=fading,
         fade_block=fade_block,
         word_bits=word_bits,
     )
-    u_hat = u_hat & _U32(clamp_mask)
-    if word_bits == 16:
-        out_ref[0] = jax.lax.bitcast_convert_type(
-            u_hat.astype(jnp.uint16), jnp.bfloat16)
-    else:
-        out_ref[0] = jax.lax.bitcast_convert_type(u_hat, jnp.float32)
-    err_ref[0, 0] = jnp.sum(_ref._popcount(u ^ u_hat)).astype(jnp.int32)
+    return u, u_hat & _U32(clamp_mask)
 
 
-def _make_batch_kernel(masked: bool, **params):
-    """Grid body, optionally masked to the first ``num_active`` client rows.
+def _make_kernel(aggregate: bool, masked: bool, *, valid_words: int,
+                 **params):
+    """Grid body over ``(tiles, clients)``, client axis innermost.
 
-    The masked variant (partial-batch grid) serves padded per-mode buckets
-    of the adaptive dispatch: rows at or beyond ``num_active`` skip the
-    whole PHY chain and write zeros, so a bucket padded to its power-of-two
-    capacity only pays for its real clients.
+    ``aggregate=False`` writes each client's demapped tile (batch kernel);
+    ``aggregate=True`` folds ``w * x_hat`` into an f32 accumulator block
+    that stays resident in VMEM across the client sweep of a tile and is
+    flushed to HBM once — a separate multiply then add, never an fma, so
+    the sum is bit-identical to ``aggregation.fedsgd_aggregate_batch`` over
+    the batch kernel's rows. Bit errors count only the first
+    ``valid_words`` global words (transmitted pad words are exactly 0).
+
+    ``masked`` adds a leading ``num_active`` scalar: clients at or beyond it
+    skip the PHY chain, count no errors, and write zeros (batch) or leave
+    the accumulator untouched (aggregate) — the partial-batch grid the
+    adaptive dispatch's padded buckets ride.
     """
-    if not masked:
-        def kernel(seed_ref, noise_ref, gain_ref, x_ref, out_ref, err_ref):
-            _batch_tile_body(pl.program_id(1), seed_ref, noise_ref, gain_ref,
-                             x_ref, out_ref, err_ref, **params)
+    block_words = params["block_words"]
 
-        return kernel
+    def kernel(*refs):
+        refs = list(refs)
+        na_ref = refs.pop(0) if masked else None
+        w_ref = refs.pop(0) if aggregate else None
+        seed_ref, noise_ref, gain_ref, x_ref, out_ref, err_ref = refs
+        # Grid coordinates are read here, outside any pl.when branch, where
+        # the interpret-mode evaluator can resolve them.
+        tile = pl.program_id(0)
+        client = pl.program_id(1)
 
-    def kernel(na_ref, seed_ref, noise_ref, gain_ref, x_ref, out_ref, err_ref):
-        tile = pl.program_id(1)
-        active = pl.program_id(0) < na_ref[0]
-
-        @pl.when(active)
-        def _():
-            _batch_tile_body(tile, seed_ref, noise_ref, gain_ref, x_ref,
-                             out_ref, err_ref, **params)
-
-        @pl.when(jnp.logical_not(active))
-        def _():
-            out_ref[0] = jnp.zeros_like(out_ref[0])
-            err_ref[0, 0] = jnp.int32(0)
-
-    return kernel
-
-
-def _aggregate_tile_body(
-    tile,
-    w_ref,
-    seed_ref,
-    noise_ref,
-    gain_ref,
-    x_ref,
-    agg_ref,
-    err_ref,
-    *,
-    bits_per_symbol: int,
-    fading: str,
-    fade_block: int,
-    clamp_mask: int,
-    block_words: int,
-    word_bits: int,
-    valid_words: int,
-):
-    """Per-(tile, client) body of the fused-aggregate grid.
-
-    Identical PHY chain to ``_batch_tile_body``, but instead of writing the
-    demapped payload back to HBM it folds ``w * x_hat`` into the f32
-    accumulator block — a separate multiply then add, never an fma, so the
-    sum is bit-identical to ``aggregation.fedsgd_aggregate_batch`` over the
-    batched kernel's rows. Bit errors are masked to the first
-    ``valid_words`` global words in-kernel (transmitted pad words are
-    exactly 0, so this equals the layered path's pad-error subtraction).
-    """
-    s_per_word = word_bits // bits_per_symbol
-    base_sym = tile.astype(_U32) * _U32(block_words * s_per_word)
-
-    x = x_ref[0]
-    if word_bits == 16:
-        u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(_U32)
-    else:
-        u = jax.lax.bitcast_convert_type(x, _U32)
-    u_hat = _ref.channel_tile(
-        u,
-        seed_ref[0],
-        base_sym,
-        noise_ref[0],
-        gain_ref[0],
-        bits_per_symbol=bits_per_symbol,
-        fading=fading,
-        fade_block=fade_block,
-        word_bits=word_bits,
-    )
-    u_hat = u_hat & _U32(clamp_mask)
-    if word_bits == 16:
-        x_hat = jax.lax.bitcast_convert_type(
-            u_hat.astype(jnp.uint16), jnp.bfloat16).astype(jnp.float32)
-    else:
-        x_hat = jax.lax.bitcast_convert_type(u_hat, jnp.float32)
-    agg_ref[0] = agg_ref[0] + w_ref[0] * x_hat
-
-    # 2-D iota (1-D iota does not lower on TPU), global word index per lane.
-    local = jax.lax.broadcasted_iota(jnp.int32, (1, block_words), 1)
-    gidx = tile * block_words + local
-    flips = _ref._popcount(u ^ u_hat)[None, :]
-    err_ref[0, 0] = jnp.sum(
-        jnp.where(gidx < valid_words, flips, _U32(0))).astype(jnp.int32)
-
-
-def _make_aggregate_kernel(masked: bool, **params):
-    """Fused-aggregate grid body over a ``(tiles, clients)`` grid.
-
-    The client axis is innermost, so the accumulator's output block
-    (``lambda ti, ci: (0, ti)``) is revisited across the whole client sweep
-    of a tile — it stays resident in VMEM and is flushed to HBM once per
-    tile, which is what removes the per-client payload round-trip. Client 0
-    zero-initializes the block; the masked variant skips the PHY chain for
-    rows at or beyond ``num_active`` (their weight never touches the sum).
-    """
-    def body(tile, client, na_ref, w_ref, seed_ref, noise_ref, gain_ref,
-             x_ref, agg_ref, err_ref):
         @pl.when(client == 0)
         def _():
-            agg_ref[0] = jnp.zeros_like(agg_ref[0])
+            err_ref[...] = jnp.zeros_like(err_ref)
+            if aggregate:
+                out_ref[...] = jnp.zeros_like(out_ref)
 
-        if na_ref is None:
-            _aggregate_tile_body(tile, w_ref, seed_ref, noise_ref, gain_ref,
-                                 x_ref, agg_ref, err_ref, **params)
+        def transmit():
+            u, u_hat = _phy_tile(tile, client, seed_ref, noise_ref, gain_ref,
+                                 x_ref, **params)
+            if aggregate:
+                # A bf16 value is the high half of the f32 with its bits.
+                f32_bits = u_hat << 16 if params["word_bits"] == 16 else u_hat
+                x_hat = jax.lax.bitcast_convert_type(f32_bits, jnp.float32)
+                out_ref[...] = out_ref[...] + w_ref[client] * x_hat
+            else:
+                out_ref[...] = u_hat
+            gidx = tile * block_words + _ref.tile_word_index(u.shape)
+            flips = jnp.where(gidx < valid_words, _ref.bit_flips(u, u_hat), 0)
+            count = jnp.sum(flips)
+            slot = _ref.tile_word_index(err_ref.shape)
+            err_ref[...] = jnp.where(slot == client, count, err_ref[...])
+
+        if not masked:
+            transmit()
             return
-
         active = client < na_ref[0]
-
-        @pl.when(active)
-        def _():
-            _aggregate_tile_body(tile, w_ref, seed_ref, noise_ref, gain_ref,
-                                 x_ref, agg_ref, err_ref, **params)
-
-        @pl.when(jnp.logical_not(active))
-        def _():
-            err_ref[0, 0] = jnp.int32(0)
-
-    if not masked:
-        def kernel(w_ref, seed_ref, noise_ref, gain_ref, x_ref,
-                   agg_ref, err_ref):
-            body(pl.program_id(0), pl.program_id(1), None, w_ref, seed_ref,
-                 noise_ref, gain_ref, x_ref, agg_ref, err_ref)
-
-        return kernel
-
-    def kernel(na_ref, w_ref, seed_ref, noise_ref, gain_ref, x_ref,
-               agg_ref, err_ref):
-        body(pl.program_id(0), pl.program_id(1), na_ref, w_ref, seed_ref,
-             noise_ref, gain_ref, x_ref, agg_ref, err_ref)
+        pl.when(active)(transmit)
+        if not aggregate:
+            @pl.when(jnp.logical_not(active))
+            def _():
+                out_ref[...] = jnp.zeros_like(out_ref)
 
     return kernel
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "bits_per_symbol",
-        "fading",
-        "fade_block",
-        "clamp_mask",
-        "block_words",
-        "word_bits",
-        "valid_words",
-        "interpret",
-    ),
+def _uplink_call(x, seeds, noise_powers, large_scale_gains, weights, *,
+                 bits_per_symbol, fading, fade_block, clamp_mask, block_words,
+                 word_bits, valid_words, interpret, num_active):
+    """Shared launch of both kernels; ``weights=None`` is the batch kernel.
+
+    Returns ``(out, bit_errors (C,) int32)`` where ``out`` is the demapped
+    ``(C, N)`` wire payload, or the ``(N,)`` f32 weighted sum.
+    """
+    c, n = x.shape
+    if n % block_words or block_words % _ref.LANES:
+        raise ValueError(
+            f"N={n} must be a multiple of block_words={block_words}, itself "
+            f"a multiple of {_ref.LANES}")
+    rows = block_words // _ref.LANES
+    tiles = n // block_words
+    aggregate = weights is not None
+    masked = num_active is not None
+    kernel = _make_kernel(
+        aggregate, masked,
+        valid_words=n if valid_words is None else valid_words,
+        bits_per_symbol=bits_per_symbol, fading=fading, fade_block=fade_block,
+        clamp_mask=clamp_mask, block_words=block_words, word_bits=word_bits)
+
+    scalars = [seeds.reshape(c).astype(_U32),
+               noise_powers.reshape(c).astype(jnp.float32),
+               large_scale_gains.reshape(c).astype(jnp.float32)]
+    if aggregate:
+        scalars.insert(0, weights.reshape(c).astype(jnp.float32))
+    if masked:
+        scalars.insert(0, jnp.reshape(jnp.asarray(num_active, jnp.int32), (1,)))
+    # Lane-dense counters: client c of a tile at flat slot c of its block.
+    err_rows = 8 * pl.cdiv(c, 8 * _ref.LANES)
+    # Inside shard_map the outputs vary over every mesh axis an operand does.
+    vma = frozenset().union(*(jax.typeof(a).vma for a in (x, *scalars)))
+
+    payload = pl.BlockSpec((None, rows, _ref.LANES),
+                           lambda ti, ci, *_: (ci, ti, 0))
+    if aggregate:
+        out_spec = pl.BlockSpec((rows, _ref.LANES), lambda ti, ci, *_: (ti, 0))
+        out_shape = jax.ShapeDtypeStruct((n // _ref.LANES, _ref.LANES),
+                                         jnp.float32, vma=vma)
+    else:
+        out_spec = payload
+        out_shape = jax.ShapeDtypeStruct((c, n // _ref.LANES, _ref.LANES),
+                                         _U32, vma=vma)
+    out, errs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(tiles, c),
+            in_specs=[payload],
+            out_specs=[
+                out_spec,
+                pl.BlockSpec((None, err_rows, _ref.LANES),
+                             lambda ti, ci, *_: (ti, 0, 0)),
+            ],
+        ),
+        out_shape=[
+            out_shape,
+            jax.ShapeDtypeStruct((tiles, err_rows, _ref.LANES), jnp.int32,
+                                 vma=vma),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*scalars, _ref.wire_words(x, word_bits).reshape(c, n // _ref.LANES,
+                                                          _ref.LANES))
+    errs = jnp.sum(errs.reshape(tiles, -1)[:, :c], axis=0)
+    if aggregate:
+        return out.reshape(n), errs
+    return _ref.wire_values(out.reshape(c, n), word_bits), errs
+
+
+_STATIC = (
+    "bits_per_symbol",
+    "fading",
+    "fade_block",
+    "clamp_mask",
+    "block_words",
+    "word_bits",
+    "valid_words",
+    "interpret",
 )
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def approx_channel_batch_aggregate_pallas(
     x: jax.Array,
     seeds: jax.Array,
@@ -335,73 +314,14 @@ def approx_channel_batch_aggregate_pallas(
       ``agg == sum_c weights[c] * x_hat[c]`` accumulated in client order,
       bit-identical to ``fedsgd_aggregate_batch`` over the batched kernel.
     """
-    c, n = x.shape
-    if n % block_words != 0:
-        raise ValueError(f"N={n} must be a multiple of block_words={block_words}")
-    tiles = n // block_words
-    if valid_words is None:
-        valid_words = n
-
-    masked = num_active is not None
-    kernel = _make_aggregate_kernel(
-        masked,
-        bits_per_symbol=bits_per_symbol,
-        fading=fading,
-        fade_block=fade_block,
-        clamp_mask=clamp_mask,
-        block_words=block_words,
-        word_bits=word_bits,
-        valid_words=valid_words,
-    )
-    wire = jnp.bfloat16 if word_bits == 16 else jnp.float32
-    client_scalar = pl.BlockSpec((1,), lambda ti, ci: (ci,))
-    in_specs = [
-        client_scalar,  # aggregation weight
-        client_scalar,  # seed
-        client_scalar,  # noise power
-        client_scalar,  # large-scale gain
-        pl.BlockSpec((1, block_words), lambda ti, ci: (ci, ti)),
-    ]
-    operands = [
-        weights.reshape(c).astype(jnp.float32),
-        seeds.reshape(c).astype(_U32),
-        noise_powers.reshape(c).astype(jnp.float32),
-        large_scale_gains.reshape(c).astype(jnp.float32),
-        x.astype(wire),
-    ]
-    if masked:
-        in_specs.insert(0, pl.BlockSpec((1,), lambda ti, ci: (0,)))
-        operands.insert(
-            0, jnp.reshape(jnp.asarray(num_active, jnp.int32), (1,)))
-    agg, errs = pl.pallas_call(
-        kernel,
-        grid=(tiles, c),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_words), lambda ti, ci: (0, ti)),
-            pl.BlockSpec((1, 1), lambda ti, ci: (ci, ti)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((c, tiles), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return agg[0], jnp.sum(errs, axis=1)
+    return _uplink_call(
+        x, seeds, noise_powers, large_scale_gains, weights,
+        bits_per_symbol=bits_per_symbol, fading=fading, fade_block=fade_block,
+        clamp_mask=clamp_mask, block_words=block_words, word_bits=word_bits,
+        valid_words=valid_words, interpret=interpret, num_active=num_active)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "bits_per_symbol",
-        "fading",
-        "fade_block",
-        "clamp_mask",
-        "block_words",
-        "word_bits",
-        "interpret",
-    ),
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def approx_channel_batch_pallas(
     x: jax.Array,
     seeds: jax.Array,
@@ -414,16 +334,19 @@ def approx_channel_batch_pallas(
     clamp_mask: int = 0xBFFFFFFF,
     block_words: int = 1024,
     word_bits: int = 32,
+    valid_words: int | None = None,
     interpret: bool = True,
     num_active=None,
 ):
-    """Batched fused PHY pipeline over a 2-D ``(clients, tiles)`` grid.
+    """Batched fused PHY pipeline over a 2-D ``(tiles, clients)`` grid.
 
     Args:
       x: ``(C, N)`` f32 (or bf16 with ``word_bits=16``), ``N % block_words == 0``.
       seeds: ``(C,)`` uint32 — one independent RNG stream per client.
       noise_powers / large_scale_gains: ``(C,)`` f32 per-client link params
         (heterogeneous SNR = varying ``noise_powers``).
+      valid_words: count only bit errors in the first ``valid_words`` words
+        of each row (``None`` = all of N); the output covers all N words.
       num_active: optional scalar (may be traced): only the first
         ``num_active`` client rows are computed; rows beyond it are masked —
         zero output, zero error count, no PHY work. This is the
@@ -434,51 +357,8 @@ def approx_channel_batch_pallas(
       ``(x_hat (C, N), bit_errors (C,) int32)``. Active row ``i`` is
       bit-identical to ``approx_channel_pallas(x[i], seeds[i], ...)``.
     """
-    c, n = x.shape
-    if n % block_words != 0:
-        raise ValueError(f"N={n} must be a multiple of block_words={block_words}")
-    tiles = n // block_words
-
-    masked = num_active is not None
-    kernel = _make_batch_kernel(
-        masked,
-        bits_per_symbol=bits_per_symbol,
-        fading=fading,
-        fade_block=fade_block,
-        clamp_mask=clamp_mask,
-        block_words=block_words,
-        word_bits=word_bits,
-    )
-    wire = jnp.bfloat16 if word_bits == 16 else jnp.float32
-    client_scalar = pl.BlockSpec((1,), lambda ci, ti: (ci,))
-    in_specs = [
-        client_scalar,  # seed
-        client_scalar,  # noise power
-        client_scalar,  # large-scale gain
-        pl.BlockSpec((1, block_words), lambda ci, ti: (ci, ti)),
-    ]
-    operands = [
-        seeds.reshape(c).astype(_U32),
-        noise_powers.reshape(c).astype(jnp.float32),
-        large_scale_gains.reshape(c).astype(jnp.float32),
-        x.astype(wire),
-    ]
-    if masked:
-        in_specs.insert(0, pl.BlockSpec((1,), lambda ci, ti: (0,)))
-        operands.insert(
-            0, jnp.reshape(jnp.asarray(num_active, jnp.int32), (1,)))
-    x_hat, errs = pl.pallas_call(
-        kernel,
-        grid=(c, tiles),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_words), lambda ci, ti: (ci, ti)),
-            pl.BlockSpec((1, 1), lambda ci, ti: (ci, ti)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((c, n), wire),
-            jax.ShapeDtypeStruct((c, tiles), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return x_hat, jnp.sum(errs, axis=1)
+    return _uplink_call(
+        x, seeds, noise_powers, large_scale_gains, None,
+        bits_per_symbol=bits_per_symbol, fading=fading, fade_block=fade_block,
+        clamp_mask=clamp_mask, block_words=block_words, word_bits=word_bits,
+        valid_words=valid_words, interpret=interpret, num_active=num_active)

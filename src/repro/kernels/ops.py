@@ -1,7 +1,8 @@
 """jit'd public wrappers for the fused approximate-channel kernel.
 
 ``approx_channel`` pads arbitrary-length vectors to the tile size and calls
-the Pallas kernel (interpret-mode on CPU, compiled on TPU).
+the Pallas kernel (compiled on TPU, interpret mode on CPU; see
+``default_interpret``).
 ``approx_channel_transmit`` adapts it to the ``TransportConfig`` interface so
 ``transport.transmit_flat(..., use_kernel=True)`` routes through the kernel.
 ``approx_channel_batch`` / ``approx_channel_transmit_batch`` are the
@@ -35,7 +36,17 @@ __all__ = [
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled Mosaic kernels on TPU, the Pallas interpreter on CPU.
+
+    Any other backend raises: the kernels are written for the TPU, and the
+    interpreter is a CPU test vehicle, not a fallback for another device.
+    """
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the approx-channel kernels run on TPU (compiled) or CPU "
+            f"(interpret mode), not on {backend!r}")
+    return backend == "cpu"
 
 
 def donation_supported() -> bool:
@@ -70,10 +81,8 @@ def approx_channel(
 ):
     """Arbitrary-length wrapper: pads with zeros to a tile multiple.
 
-    The kernel counts bit errors over the whole tile, padding included; since
-    the transmitted pad words are exactly 0, every set bit in a *received*
-    pad word is a counted error — we subtract them here so ``bit_errors``
-    covers only the true payload.
+    The kernel counts bit errors over the first ``N`` words only, so
+    ``bit_errors`` covers the true payload and not the padding.
     """
     n = x.shape[0]
     pad = (-n) % block_words
@@ -90,21 +99,10 @@ def approx_channel(
         clamp_mask=clamp_mask,
         block_words=block_words,
         word_bits=word_bits,
+        valid_words=n,
         interpret=interpret,
     )
-    errs = errs - _padding_errors(x_hat[n:], word_bits)
     return x_hat[:n], errs
-
-
-def _padding_errors(pad_hat: jax.Array, word_bits: int) -> jax.Array:
-    """Bit errors the kernel counted on zero pad words (= received popcount)."""
-    from repro.kernels import ref as _ref
-
-    if word_bits == 16:
-        u = jax.lax.bitcast_convert_type(pad_hat, jnp.uint16).astype(jnp.uint32)
-    else:
-        u = jax.lax.bitcast_convert_type(pad_hat, jnp.uint32)
-    return jnp.sum(_ref._popcount(u), dtype=jnp.int32)
 
 
 def _transport_kernel_params(cfg):
@@ -174,8 +172,8 @@ def _batch_impl(
 ):
     """Batched arbitrary-length wrapper: pads ``(C, N)`` payloads along the
     payload dim to a tile multiple, one fused kernel launch for all clients.
-    Returns ``(x_hat (C, N), bit_errors (C,) int32)``; errors counted on the
-    zero padding are subtracted per client (see ``approx_channel``).
+    Returns ``(x_hat (C, N), bit_errors (C,) int32)``; errors are counted
+    on the first ``N`` words only (see ``approx_channel``).
     ``num_active`` masks the tail client rows (partial-batch grid): masked
     rows cost no PHY work and return zeros — the adaptive dispatch's padded
     buckets discard them."""
@@ -194,10 +192,10 @@ def _batch_impl(
         clamp_mask=clamp_mask,
         block_words=block_words,
         word_bits=word_bits,
+        valid_words=n,
         interpret=interpret,
         num_active=num_active,
     )
-    errs = errs - jax.vmap(lambda row: _padding_errors(row[n:], word_bits))(x_hat)
     return x_hat[:, :n], errs
 
 
@@ -287,9 +285,8 @@ def _batch_aggregate_impl(
     Pads ``(C, N)`` payloads to a tile multiple and runs the aggregating
     kernel: the per-client demapped payload never materializes in HBM — the
     only payload-sized output is the f32 accumulator. Bit errors are masked
-    to the first ``N`` words *inside* the kernel (``valid_words``), so no
-    pad-error subtraction (which would need the per-client x_hat) happens
-    here. Returns ``(agg (N,) float32, bit_errors (C,) int32)``.
+    to the first ``N`` words inside the kernel (``valid_words``). Returns
+    ``(agg (N,) float32, bit_errors (C,) int32)``.
     """
     c, n = x.shape
     pad = (-n) % block_words

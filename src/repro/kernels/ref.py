@@ -25,9 +25,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ref_approx_channel", "CHANNEL_STATIC_ARGS"]
+__all__ = ["ref_approx_channel"]
 
 _U32 = jnp.uint32
+LANES = 128  # words per tile row: the TPU vector lane width
 _TWO_PI = 6.283185307179586
 
 # Streams for the counter RNG (arbitrary odd constants).
@@ -52,8 +53,12 @@ def hash_u32(seed: jax.Array, idx: jax.Array, stream: int) -> jax.Array:
 
 
 def uniform01(h: jax.Array) -> jax.Array:
-    """uint32 hash -> uniform float32 in (0, 1]."""
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / 16777216.0) + jnp.float32(2.0**-25)
+    """uint32 hash -> uniform float32 in (0, 1].
+
+    The 24-bit integer goes through int32 on its way to float32 (exact below
+    2^24): Mosaic has no uint32 -> float32 conversion."""
+    h24 = jax.lax.bitcast_convert_type(h >> 8, jnp.int32)
+    return h24.astype(jnp.float32) * jnp.float32(1.0 / 16777216.0) + jnp.float32(2.0**-25)
 
 
 def gauss_pair(seed: jax.Array, idx: jax.Array, stream: int):
@@ -85,20 +90,28 @@ def _popcount(x):
     return (x * _U32(0x01010101)) >> 24
 
 
-# Static (python-level) parameters shared by kernel and reference.
-CHANNEL_STATIC_ARGS = (
-    "bits_per_symbol",
-    "fading",
-    "fade_block",
-    "clamp_mask",
-    "block_words",
-)
+def _u32(x: jax.Array) -> jax.Array:
+    """Reinterpret non-negative int32 as uint32 (no conversion op)."""
+    return jax.lax.bitcast_convert_type(x, _U32)
+
+
+def _i32(x: jax.Array) -> jax.Array:
+    """Reinterpret uint32 as int32: Mosaic converts and reduces only signed
+    integers, and every value routed through here is below 2^31."""
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def tile_word_index(shape) -> jax.Array:
+    """Row-major word index within a ``(rows, 128)`` tile (int32)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 2)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return rows * shape[-1] + lanes
 
 
 def channel_tile(
-    u: jax.Array,  # (BW,) uint32 words of one tile (low word_bits used)
+    u: jax.Array,  # (R, 128) uint32 words of one tile (low word_bits used)
     seed: jax.Array,  # () uint32
-    base_sym: jax.Array,  # () uint32 — global index of this tile's 1st symbol
+    base_sym: jax.Array,  # () int32 — global index of this tile's 1st symbol
     noise_power: jax.Array,  # () f32
     large_scale_gain: jax.Array,  # () f32
     *,
@@ -109,37 +122,42 @@ def channel_tile(
 ) -> jax.Array:
     """Shared tile body: words -> noisy received words (pre-clamp).
 
+    A tile is ``R x 128`` words in row-major order (word ``w`` sits at row
+    ``w // 128``, lane ``w % 128``), the layout of one VMEM block. Symbol
+    ``j`` (MSB-first) of word ``w`` is transmitted at tile position
+    ``j * R * 128 + w``: the block-local row/column interleave.
+
     ``word_bits=16`` implements the bf16 wire format (same exponent layout
     as f32, so the clamp prior transfers; half the symbols per word)."""
     k = bits_per_symbol
     p = k // 2
     L = 1 << p
-    bw = u.shape[0]
+    bw = u.size
     s_per_word = word_bits // k
     amp = math.sqrt(3.0 / (2.0 * (L * L - 1)))
+    shape = (s_per_word,) + u.shape
 
-    # words -> symbols, MSB-first: (BW, S)
-    shifts = _U32(word_bits - k * (jnp.arange(s_per_word, dtype=_U32) + 1))
-    sym = (u[:, None] >> shifts[None, :]) & _U32((1 << k) - 1)
-    # block-local row/column interleave -> transmit order (S, BW)
-    stream = jnp.transpose(sym)
+    # words -> symbols, MSB-first, already in transmit order: (S, R, 128)
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    shifts = _u32(word_bits - k * (j + 1))
+    stream = (jnp.broadcast_to(u[None], shape) >> shifts) & _U32((1 << k) - 1)
 
     # split to Gray axis bits (alternating I/Q allocation, MSB-first)
     gi = jnp.zeros_like(stream)
     gq = jnp.zeros_like(stream)
-    for j in range(p):
-        bi = (stream >> _U32(k - 1 - 2 * j)) & _U32(1)
-        bq = (stream >> _U32(k - 2 - 2 * j)) & _U32(1)
-        gi = gi | (bi << _U32(p - 1 - j))
-        gq = gq | (bq << _U32(p - 1 - j))
-    li = gray_decode(gi).astype(jnp.float32)
-    lq = gray_decode(gq).astype(jnp.float32)
+    for b in range(p):
+        bi = (stream >> _U32(k - 1 - 2 * b)) & _U32(1)
+        bq = (stream >> _U32(k - 2 - 2 * b)) & _U32(1)
+        gi = gi | (bi << _U32(p - 1 - b))
+        gq = gq | (bq << _U32(p - 1 - b))
+    li = _i32(gray_decode(gi)).astype(jnp.float32)
+    lq = _i32(gray_decode(gq)).astype(jnp.float32)
     s_re = (2.0 * li - (L - 1)) * jnp.float32(amp)
     s_im = (2.0 * lq - (L - 1)) * jnp.float32(amp)
 
-    # global symbol index in transmit order
-    gidx = base_sym + jax.lax.broadcasted_iota(_U32, stream.shape, 0) * _U32(bw) \
-        + jax.lax.broadcasted_iota(_U32, stream.shape, 1)
+    # global symbol index in transmit order; int32 arithmetic wraps to the
+    # same bits as uint32 arithmetic mod 2^32
+    gidx = _u32(base_sym + j * bw + tile_word_index(shape))
 
     # channel: r = c s + n ; receiver equalizes y = s + n/c
     n_re, n_im = gauss_pair(seed, gidx, _STREAM_NOISE)
@@ -165,21 +183,42 @@ def channel_tile(
 
     def axis_level(x):
         lvl = jnp.round((x * inv + (L - 1)) * 0.5)
-        return jnp.clip(lvl, 0, L - 1).astype(_U32)
+        return _u32(jnp.clip(lvl, 0, L - 1).astype(jnp.int32))
 
     gi_hat = gray_encode(axis_level(y_re))
     gq_hat = gray_encode(axis_level(y_im))
     rx = jnp.zeros_like(stream)
-    for j in range(p):
-        bi = (gi_hat >> _U32(p - 1 - j)) & _U32(1)
-        bq = (gq_hat >> _U32(p - 1 - j)) & _U32(1)
-        rx = rx | (bi << _U32(k - 1 - 2 * j))
-        rx = rx | (bq << _U32(k - 2 - 2 * j))
+    for b in range(p):
+        bi = (gi_hat >> _U32(p - 1 - b)) & _U32(1)
+        bq = (gq_hat >> _U32(p - 1 - b)) & _U32(1)
+        rx = rx | (bi << _U32(k - 1 - 2 * b))
+        rx = rx | (bq << _U32(k - 2 - 2 * b))
 
-    # de-interleave, reassemble words
-    rx_sym = jnp.transpose(rx)  # (BW, S)
-    u_hat = jnp.sum(rx_sym << shifts[None, :], axis=-1, dtype=_U32)
+    # de-interleave: reassemble each word from its disjoint symbol fields
+    u_hat = rx[0] << _U32(word_bits - k)
+    for s in range(1, s_per_word):
+        u_hat = u_hat | (rx[s] << _U32(word_bits - k * (s + 1)))
     return u_hat
+
+
+def bit_flips(u: jax.Array, u_hat: jax.Array) -> jax.Array:
+    """Per-word count of flipped bits, int32."""
+    return _i32(_popcount(u ^ u_hat))
+
+
+def wire_words(x: jax.Array, word_bits: int) -> jax.Array:
+    """Wire floats -> uint32 words (bf16 zero-extended when word_bits=16)."""
+    if word_bits == 16:
+        return jax.lax.bitcast_convert_type(
+            x.astype(jnp.bfloat16), jnp.uint16).astype(_U32)
+    return jax.lax.bitcast_convert_type(x.astype(jnp.float32), _U32)
+
+
+def wire_values(u: jax.Array, word_bits: int) -> jax.Array:
+    """uint32 words -> wire floats (bf16 when word_bits=16, else f32)."""
+    if word_bits == 16:
+        return jax.lax.bitcast_convert_type(u.astype(jnp.uint16), jnp.bfloat16)
+    return jax.lax.bitcast_convert_type(u, jnp.float32)
 
 
 def ref_approx_channel(
@@ -194,18 +233,20 @@ def ref_approx_channel(
     clamp_mask: int = 0xBFFFFFFF,
     block_words: int = 1024,
     word_bits: int = 32,
+    valid_words: int | None = None,
 ):
     """Oracle for the fused kernel. x: (N,) f32 (or bf16 when word_bits=16),
-    N % block_words == 0."""
+    N % block_words == 0, block_words % 128 == 0. Bit errors are counted on
+    the first ``valid_words`` words (``None`` = all N), as the kernel does."""
     n = x.shape[0]
-    assert n % block_words == 0, (n, block_words)
+    if n % block_words or block_words % LANES:
+        raise ValueError(
+            f"N={n} must be a multiple of block_words={block_words}, itself "
+            f"a multiple of {LANES}")
     s_per_word = word_bits // bits_per_symbol
-    if word_bits == 16:
-        u = jax.lax.bitcast_convert_type(x.astype(jnp.bfloat16), jnp.uint16).astype(_U32)
-    else:
-        u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), _U32)
-    tiles = u.reshape(-1, block_words)
-    base = (jnp.arange(tiles.shape[0], dtype=_U32) * _U32(block_words * s_per_word))
+    u = wire_words(x, word_bits)
+    tiles = u.reshape(-1, block_words // LANES, LANES)
+    base = jnp.arange(tiles.shape[0], dtype=jnp.int32) * (block_words * s_per_word)
 
     def per_tile(tile, b):
         return channel_tile(
@@ -217,9 +258,5 @@ def ref_approx_channel(
 
     u_hat = jax.vmap(per_tile)(tiles, base).reshape(-1)
     u_hat = u_hat & _U32(clamp_mask)
-    errs = jnp.sum(_popcount(u ^ u_hat), dtype=jnp.int32)
-    if word_bits == 16:
-        out = jax.lax.bitcast_convert_type(u_hat.astype(jnp.uint16), jnp.bfloat16)
-    else:
-        out = jax.lax.bitcast_convert_type(u_hat, jnp.float32)
-    return out, errs
+    errs = jnp.sum(bit_flips(u, u_hat)[:valid_words])
+    return wire_values(u_hat, word_bits), errs

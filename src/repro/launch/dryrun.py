@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (arch x shape x mesh) lowers and compiles.
 
 For each combination this driver:
@@ -18,10 +14,21 @@ Usage:
   python -m repro.launch.dryrun --arch yi-6b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both --out artifacts/dryrun
 
-NOTE the XLA_FLAGS line above MUST run before any other import (jax locks
-the device count on first init); keep it the first statement in this file.
-Smoke tests and benchmarks never import this module.
+The meshes are 512 placeholder host devices: run as a script, the module
+asks XLA for them and pins jax to the CPU platform before anything imports
+jax, so this is a compile-only tool on any machine. Importing the module
+changes nothing.
 """
+
+import os
+
+if __name__ == "__main__":
+    # Before the imports below: importing repro.core initializes the
+    # backend, which fixes the device count. Appended, so caller-set XLA
+    # flags survive.
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=512")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import dataclasses

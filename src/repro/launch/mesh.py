@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "data_axes", "MESH_SHAPES"]
+__all__ = ["make_mesh", "make_production_mesh", "data_axes", "MESH_SHAPES"]
 
 MESH_SHAPES = {
     "single": ((16, 16), ("data", "model")),
@@ -16,10 +16,23 @@ MESH_SHAPES = {
 }
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """A mesh whose axes are all ``Auto``.
+
+    The code places arrays with ``with_sharding_constraint`` hints
+    (``models.layers.maybe_shard``) and ``shard_map``; both assume
+    compiler-propagated (``Auto``) axes. ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which a constraint becomes an assertion.
+    """
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple[str, ...]:

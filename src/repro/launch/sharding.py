@@ -35,6 +35,35 @@ from repro.launch.mesh import data_axes
 
 Axis = Any  # str | tuple[str, ...] | None
 
+def _over_clients(body, mesh, axes, operands, *, check_vma=True):
+    """``body(offset, *local_operands) -> (x_hat, stats)`` with every
+    operand's leading (client) dim sharded over ``axes``; ``offset`` is the
+    global index of the shard's first client.
+
+    The ``shard_map`` is jitted whole (an eager one dispatches its body op
+    by op) and returns per-client, client-sharded values only.
+    """
+    n_shards = math.prod(mesh.shape[a] for a in axes)
+    num_clients = operands[0].shape[0]
+    if num_clients % n_shards != 0:
+        raise ValueError(
+            f"{num_clients} clients do not shard evenly over {n_shards} devices"
+        )
+    local_clients = num_clients // n_shards
+    ax_spec = axes if len(axes) > 1 else axes[0]
+
+    def local(*ops):
+        idx = jnp.int32(0)
+        for a in axes:
+            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+        return body(idx * local_clients, *ops)
+
+    in_specs = (P(ax_spec, None),) + (P(ax_spec),) * (len(operands) - 1)
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, check_vma=check_vma, in_specs=in_specs,
+        out_specs=(P(ax_spec, None), P(ax_spec)),
+    ))(*operands)
+
 
 def shard_transmit_batch(x, key, cfg, mesh, *, axis_names=None, snr_db=None):
     """Run the batched uplink with the client dim sharded over ``axis_names``.
@@ -56,50 +85,24 @@ def shard_transmit_batch(x, key, cfg, mesh, *, axis_names=None, snr_db=None):
       ``(num_clients, N)`` outputs and per-client ``TxStats``.
     """
     from repro.core import transport as transport_lib
+    from repro.kernels.ops import default_interpret
 
     axes = tuple(axis_names) if axis_names is not None else data_axes(mesh)
     if not axes:  # e.g. a pure tensor-parallel mesh: nothing to shard over
         return transport_lib.transmit_batch(x, key, cfg, snr_db=snr_db)
-    n_shards = math.prod(mesh.shape[a] for a in axes)
-    num_clients = x.shape[0]
-    if num_clients % n_shards != 0:
-        raise ValueError(
-            f"{num_clients} clients do not shard evenly over {n_shards} devices"
-        )
-    local_clients = num_clients // n_shards
-    ax_spec = axes if len(axes) > 1 else axes[0]
+    snr_vec = transport_lib._resolve_batch_snr(cfg, x.shape[0], snr_db)
+    operands = (x,) if snr_vec is None else (x, snr_vec)
 
-    snr_vec = transport_lib._resolve_batch_snr(cfg, num_clients, snr_db)
-
-    def shard_index():
-        idx = jnp.int32(0)
-        for a in axes:
-            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
-        return idx
-
-    if snr_vec is None:
-
-        def local(xl):
-            offset = shard_index() * local_clients
-            return transport_lib.transmit_batch(
-                xl, key, cfg, client_offset=offset)
-
-        return jax.shard_map(
-            local, mesh=mesh,
-            in_specs=P(ax_spec, None),
-            out_specs=(P(ax_spec, None), P(ax_spec)),
-        )(x)
-
-    def local(xl, sl):
-        offset = shard_index() * local_clients
+    def body(offset, xl, *sl):
         return transport_lib.transmit_batch(
-            xl, key, cfg, snr_db=sl, client_offset=offset)
+            xl, key, cfg, snr_db=sl[0] if sl else None, client_offset=offset)
 
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(ax_spec, None), P(ax_spec)),
-        out_specs=(P(ax_spec, None), P(ax_spec)),
-    )(x, snr_vec)
+    # The Pallas interpreter (the kernel off-TPU) indexes the per-client
+    # scalar refs with the unvarying grid coordinate, which the varying-axes
+    # check refuses; the compiled kernel passes it.
+    interpreted = cfg.use_kernel and default_interpret()
+    return _over_clients(body, mesh, axes, operands,
+                         check_vma=not interpreted)
 
 
 def shard_transmit_batch_adaptive(x, key, cfgs, mode_idx, mesh, *,
@@ -110,67 +113,37 @@ def shard_transmit_batch_adaptive(x, key, cfgs, mode_idx, mesh, *,
     globally indexed fold_in keys; ``mode_idx`` (and a per-client ``snr_db``)
     shard along the clients, so the result — received payloads and per-client
     ``TxStats`` including ``mode_idx`` — is bit-identical, whatever the mesh
-    shape, to the unsharded call *on the kernel-cleared table* (for
-    kernel-free tables that is simply the unsharded call; ``use_kernel``
-    rows are cleared here, so their jnp rows draw a different channel
-    realization than an unsharded bucketed call that kept the kernel).
+    shape, to the unsharded ``dispatch="select"`` call.
 
     Inside the ``shard_map`` body the mode vector is traced, so the per-shard
     dispatch is necessarily ``"select"`` (every shard pays every mode's
-    FLOPs for its cohort). ``use_kernel`` rows are cleared up front — the
-    Pallas grid cannot lower in the traced select body, and the jnp rows
-    draw their own (equally valid) channel realization; the single-host
-    bucketed dispatch is the fast path when the cohort fits one process.
+    FLOPs for its cohort). The Pallas grid cannot lower in the traced select
+    body, so a table with ``use_kernel`` rows is refused: clear them with
+    ``transport.clear_kernel_rows`` (the jnp rows draw a different, equally
+    valid, channel realization). The single-host bucketed dispatch is the
+    kernel path when the cohort fits one process.
     """
     from repro.core import transport as transport_lib
 
-    cfgs = transport_lib.clear_kernel_rows(cfgs)
+    if any(c.use_kernel for c in cfgs):
+        raise ValueError(
+            "shard_transmit_batch_adaptive runs the select dispatch, which "
+            "has no kernel rows; pass transport.clear_kernel_rows(cfgs)")
     axes = tuple(axis_names) if axis_names is not None else data_axes(mesh)
     if not axes:
         return transport_lib.transmit_batch_adaptive(
             x, key, cfgs, mode_idx, snr_db=snr_db)
-    n_shards = math.prod(mesh.shape[a] for a in axes)
-    num_clients = x.shape[0]
-    if num_clients % n_shards != 0:
-        raise ValueError(
-            f"{num_clients} clients do not shard evenly over {n_shards} devices"
-        )
-    local_clients = num_clients // n_shards
-    ax_spec = axes if len(axes) > 1 else axes[0]
-
-    snr_vec = transport_lib._resolve_batch_snr(cfgs[0], num_clients, snr_db)
+    snr_vec = transport_lib._resolve_batch_snr(cfgs[0], x.shape[0], snr_db)
     mode_arr = jnp.asarray(mode_idx, jnp.int32)
+    operands = ((x, mode_arr) if snr_vec is None
+                else (x, mode_arr, snr_vec))
 
-    def shard_index():
-        idx = jnp.int32(0)
-        for a in axes:
-            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
-        return idx
-
-    if snr_vec is None:
-
-        def local(xl, ml):
-            offset = shard_index() * local_clients
-            return transport_lib.transmit_batch_adaptive(
-                xl, key, cfgs, ml, client_offset=offset, dispatch="select")
-
-        return jax.shard_map(
-            local, mesh=mesh,
-            in_specs=(P(ax_spec, None), P(ax_spec)),
-            out_specs=(P(ax_spec, None), P(ax_spec)),
-        )(x, mode_arr)
-
-    def local(xl, ml, sl):
-        offset = shard_index() * local_clients
+    def body(offset, xl, ml, *sl):
         return transport_lib.transmit_batch_adaptive(
-            xl, key, cfgs, ml, snr_db=sl, client_offset=offset,
-            dispatch="select")
+            xl, key, cfgs, ml, snr_db=sl[0] if sl else None,
+            client_offset=offset, dispatch="select")
 
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(ax_spec, None), P(ax_spec), P(ax_spec)),
-        out_specs=(P(ax_spec, None), P(ax_spec)),
-    )(x, mode_arr, snr_vec)
+    return _over_clients(body, mesh, axes, operands)
 
 
 import re

@@ -27,6 +27,7 @@ from repro.core import transport as transport_lib
 from repro.data.tokens import TokenStream
 from repro.launch import sharding as sh
 from repro.launch import steps as steps_lib
+from repro.launch.mesh import make_mesh
 from repro.models import registry as R
 from repro.optim.sgd import sgd as make_sgd
 
@@ -67,7 +68,7 @@ def main(argv=None):
         shape = tuple(int(x) for x in args.mesh_shape.split(","))
     else:
         shape = (n_dev, 1)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     print(f"mesh {dict(mesh.shape)} devices={n_dev}")
 
     key = jax.random.PRNGKey(0)

@@ -70,7 +70,7 @@ def moe_ffn(x: jax.Array, p, cfg):
     aux = E * jnp.sum(density * mean_prob)
 
     flat_e = topi.reshape(-1)  # (T*K,)
-    flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
+    flat_t = jnp.arange(T * K, dtype=jnp.int32) // K  # token of each slot
     flat_w = topv.reshape(-1)
 
     order = jnp.argsort(flat_e)
@@ -150,7 +150,7 @@ def _local_dispatch(x2, p, cfg, C):
     aux = E * jnp.sum(density * jnp.mean(probs, axis=0))
 
     flat_e = topi.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
+    flat_t = jnp.arange(T * K, dtype=jnp.int32) // K  # token of each slot
     flat_w = topv.reshape(-1)
     order = jnp.argsort(flat_e)
     se, st, sw = flat_e[order], flat_t[order], flat_w[order]
@@ -168,17 +168,9 @@ def moe_ffn_shardmap(x: jax.Array, p, cfg):
     """Expert-parallel MoE: (B, S, D) -> (out, aux). Falls back to the dense
     dispatch when no auto data axes exist (e.g. inside the per-client
     uplink shard_map, where experts are replicated per client cohort)."""
-    from repro.compat import LEGACY_JAX
-
     axes, nd = _usable_data_axes(cfg)
     E = cfg.n_experts
     if not axes or nd == 1 or E % nd != 0 or x.ndim != 3 or x.shape[0] % nd != 0:
-        return moe_ffn(x, p, cfg)
-    if LEGACY_JAX:
-        # Legacy XLA crashes on tiled all_to_all inside a partial-manual
-        # shard_map (spmd_partitioner IsManualSubgroup CHECK); use the dense
-        # dispatch there — numerically identical, just without the
-        # expert-parallel communication schedule.
         return moe_ffn(x, p, cfg)
     from jax.sharding import PartitionSpec as P
 

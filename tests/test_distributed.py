@@ -13,6 +13,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
         "import os\n"
         f"os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={devices}'\n"
         "import sys; sys.path.insert(0, 'src')\n"
+        "from repro.launch.mesh import make_mesh\n"
     )
     out = subprocess.run(
         [sys.executable, "-c", env_code + textwrap.dedent(code)],
@@ -31,7 +32,7 @@ def test_approx_allreduce_matches_mean_at_high_snr():
         from jax.sharding import PartitionSpec as P
         from repro.core import aggregation as AGG, transport as T, channel as CH
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         cfg = T.TransportConfig(mode="approx", channel=CH.ChannelConfig(snr_db=60.0, fading="awgn"))
         g = jnp.linspace(-0.9, 0.9, 4 * 64).reshape(4, 64)
 
@@ -62,7 +63,7 @@ def test_train_step_approx_runs_and_descends():
         from repro.optim.sgd import sgd as make_sgd
 
         cfg = get_config("qwen2-1.5b").reduced(n_layers=2, d_model=64, d_ff=128, vocab_size=128)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         tcfg = T.TransportConfig(mode="approx", channel=CH.ChannelConfig(snr_db=20.0))
         opt = make_sgd(0.2)
         key = jax.random.PRNGKey(0)
@@ -96,7 +97,7 @@ def test_per_shard_corruption_step():
         from repro.optim.sgd import sgd as make_sgd
 
         cfg = get_config("qwen2-1.5b").reduced(n_layers=2, d_model=64, d_ff=128, vocab_size=128)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         tcfg = T.TransportConfig(mode="approx", channel=CH.ChannelConfig(snr_db=25.0))
         opt = make_sgd(0.2)
         key = jax.random.PRNGKey(0)
@@ -137,7 +138,7 @@ def test_expert_parallel_moe_matches_dense():
         cfg = get_config("kimi-k2-1t-a32b").reduced(
             d_model=64, moe_d_ff=32, n_experts=8, top_k=2)
         cfg = dataclasses.replace(cfg, capacity_factor=4.0, n_shared_experts=1)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         p = MOE.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model), jnp.float32)
         with jax.set_mesh(mesh):
@@ -172,7 +173,7 @@ def test_bf16_wire_train_step():
         from repro.optim.sgd import sgd as make_sgd
 
         cfg = get_config("qwen2-1.5b").reduced(n_layers=2, d_model=64, d_ff=128, vocab_size=128)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         opt = make_sgd(0.2)
         key = jax.random.PRNGKey(0)
         params = R.init_params(key, cfg)

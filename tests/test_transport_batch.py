@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import channel as CH
 from repro.core import transport as T
+from repro.launch.mesh import make_mesh
 
 M, N = 8, 2048
 
@@ -148,7 +149,7 @@ def test_sharded_dispatch_matches_unsharded(payloads):
     (globally-indexed fold_in keys), homogeneous and heterogeneous."""
     from repro.launch.sharding import shard_transmit_batch
 
-    mesh = jax.make_mesh((1,), ("data",))  # 1 CPU device in the test runner
+    mesh = make_mesh((1,), ("data",))  # 1 CPU device in the test runner
     cfg = _cfg(mode="approx")
     key = jax.random.PRNGKey(10)
     ref, rst = T.transmit_batch(payloads, key, cfg)
@@ -547,10 +548,10 @@ def test_adaptive_accepts_shape_normalized_channels(payloads):
 
 
 def test_select_consumers_clear_kernel_rows(payloads):
-    """Regression: a kernel-enabled mode table must not brick the
-    select-pinned consumers (fused FL round, shard_map) — they clear
-    ``use_kernel`` themselves (PR-2 behavior) instead of hitting the
-    engine's ValueError."""
+    """The select-pinned consumers of a kernel-enabled mode table: the FL
+    round clears ``use_kernel`` through ``select_mode_cfgs``; the sharded
+    dispatch refuses the table (no silent swap to the jnp rows) and matches
+    the unsharded call bit for bit once the caller clears it."""
     from repro.fl.loop import select_mode_cfgs
     from repro.launch.sharding import shard_transmit_batch_adaptive
     from repro.link import policy as P
@@ -569,11 +570,11 @@ def test_select_consumers_clear_kernel_rows(payloads):
 
     mode = np.array([0, 1, 2, 3, 3, 2, 1, 0], np.int32)
     key = jax.random.PRNGKey(42)
-    # The sharded dispatch accepts the kernel table (clearing internally)
-    # and matches the cleared-table reference bit for bit.
-    mesh = jax.make_mesh((1,), ("data",))
-    out, _ = shard_transmit_batch_adaptive(payloads, key, kernel_cfgs, mode,
-                                           mesh)
+    mesh = make_mesh((1,), ("data",))
+    with pytest.raises(ValueError, match="clear_kernel_rows"):
+        shard_transmit_batch_adaptive(payloads, key, kernel_cfgs, mode, mesh)
+    out, _ = shard_transmit_batch_adaptive(
+        payloads, key, T.clear_kernel_rows(kernel_cfgs), mode, mesh)
     ref, _ = T.transmit_batch_adaptive(payloads, key, cleared, mode)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
@@ -583,7 +584,7 @@ def test_sharded_adaptive_matches_unsharded(payloads):
     heterogeneous SNR, on a 1-device mesh."""
     from repro.launch.sharding import shard_transmit_batch_adaptive
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     cfgs = _mode_table()
     key = jax.random.PRNGKey(41)
     mode = np.array([0, 1, 2, 3, 3, 2, 1, 0], np.int32)
