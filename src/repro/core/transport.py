@@ -1155,9 +1155,10 @@ def transmit_pytree_batch(tree: Any, key: jax.Array, cfg: TransportConfig, *,
       ``(tree_hat, stats)`` with the input structure/shapes/dtypes restored
       and per-client :class:`TxStats` (``(num_clients,)`` fields).
     """
-    flat, spec = _flatten_client_tree(tree)
-    flat_hat, stats = transmit_batch(flat, key, cfg, snr_db=snr_db)
-    return _unflatten_client_tree(flat_hat, spec), stats
+    with jax.named_scope("fl_uplink"):
+        flat, spec = _flatten_client_tree(tree)
+        flat_hat, stats = transmit_batch(flat, key, cfg, snr_db=snr_db)
+        return _unflatten_client_tree(flat_hat, spec), stats
 
 
 def transmit_pytree_batch_adaptive(tree: Any, key: jax.Array, cfgs, mode_idx,
@@ -1168,10 +1169,11 @@ def transmit_pytree_batch_adaptive(tree: Any, key: jax.Array, cfgs, mode_idx,
     with a per-client mode table dispatch — the entry point the
     scenario-driven FL loops feed each round's gradients through.
     """
-    flat, spec = _flatten_client_tree(tree)
-    flat_hat, stats = transmit_batch_adaptive(
-        flat, key, cfgs, mode_idx, snr_db=snr_db, dispatch=dispatch)
-    return _unflatten_client_tree(flat_hat, spec), stats
+    with jax.named_scope("fl_uplink"):
+        flat, spec = _flatten_client_tree(tree)
+        flat_hat, stats = transmit_batch_adaptive(
+            flat, key, cfgs, mode_idx, snr_db=snr_db, dispatch=dispatch)
+        return _unflatten_client_tree(flat_hat, spec), stats
 
 
 def _unflatten_aggregate_tree(flat_agg: jax.Array, spec) -> Any:
@@ -1199,10 +1201,11 @@ def transmit_pytree_batch_aggregate(tree: Any, key: jax.Array,
     the shape ``algo.apply`` expects from the layered
     ``fedsgd_aggregate_batch`` tail.
     """
-    flat, spec = _flatten_client_tree(tree)
-    agg, stats = transmit_batch_aggregate(
-        flat, key, cfg, weights, snr_db=snr_db, donate=donate)
-    return _unflatten_aggregate_tree(agg, spec), stats
+    with jax.named_scope("fl_uplink"):
+        flat, spec = _flatten_client_tree(tree)
+        agg, stats = transmit_batch_aggregate(
+            flat, key, cfg, weights, snr_db=snr_db, donate=donate)
+        return _unflatten_aggregate_tree(agg, spec), stats
 
 
 def transmit_pytree_batch_adaptive_aggregate(tree: Any, key: jax.Array, cfgs,
@@ -1213,10 +1216,11 @@ def transmit_pytree_batch_adaptive_aggregate(tree: Any, key: jax.Array, cfgs,
     entry point the scenario-driven fused FL rounds feed each round's
     gradients through (bucketed dispatch, globally pre-normalized weights).
     """
-    flat, spec = _flatten_client_tree(tree)
-    agg, stats = transmit_batch_adaptive_aggregate(
-        flat, key, cfgs, mode_idx, weights, snr_db=snr_db, donate=donate)
-    return _unflatten_aggregate_tree(agg, spec), stats
+    with jax.named_scope("fl_uplink"):
+        flat, spec = _flatten_client_tree(tree)
+        agg, stats = transmit_batch_adaptive_aggregate(
+            flat, key, cfgs, mode_idx, weights, snr_db=snr_db, donate=donate)
+        return _unflatten_aggregate_tree(agg, spec), stats
 
 
 def _broadcast_payload(x: jax.Array, num_clients: int) -> jax.Array:
@@ -1305,20 +1309,22 @@ def transmit_pytree_broadcast(tree: Any, key: jax.Array, cfg: TransportConfig,
     leading ``(num_clients,)`` dimension — client ``i``'s received copy is
     ``tree_map(lambda l: l[i], out)``. ``stats`` fields are per-client.
     """
-    flat, spec = _flatten_global_tree(tree)
-    flat_hat, stats = transmit_broadcast(flat, key, cfg, num_clients,
-                                         snr_db=snr_db)
-    return _unflatten_broadcast_tree(flat_hat, spec), stats
+    with jax.named_scope("fl_downlink"):
+        flat, spec = _flatten_global_tree(tree)
+        flat_hat, stats = transmit_broadcast(flat, key, cfg, num_clients,
+                                             snr_db=snr_db)
+        return _unflatten_broadcast_tree(flat_hat, spec), stats
 
 
 def transmit_pytree_broadcast_adaptive(tree: Any, key: jax.Array, cfgs,
                                        mode_idx, *, snr_db=None,
                                        dispatch: str = "auto"):
     """Pytree front-end of :func:`transmit_broadcast_adaptive`."""
-    flat, spec = _flatten_global_tree(tree)
-    flat_hat, stats = transmit_broadcast_adaptive(
-        flat, key, cfgs, mode_idx, snr_db=snr_db, dispatch=dispatch)
-    return _unflatten_broadcast_tree(flat_hat, spec), stats
+    with jax.named_scope("fl_downlink"):
+        flat, spec = _flatten_global_tree(tree)
+        flat_hat, stats = transmit_broadcast_adaptive(
+            flat, key, cfgs, mode_idx, snr_db=snr_db, dispatch=dispatch)
+        return _unflatten_broadcast_tree(flat_hat, spec), stats
 
 
 def transmit_sparse(values: jax.Array, indices: jax.Array, dim: int,

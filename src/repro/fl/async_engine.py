@@ -273,15 +273,15 @@ class AsyncRoundEngine(engine_lib.RoundEngine):
         # weights.
         @jax.jit
         def agg_apply_mean(params, aux, hat):
-            agg = jax.tree_util.tree_map(lambda g: jnp.mean(g, axis=0), hat)
-            return algo.apply(params, aux, agg)
+            return algo.apply(params, aux, engine_lib.cohort_mean(hat))
 
         @jax.jit
         def agg_apply_one(params, aux, hat, wvec):
-            total = jnp.sum(wvec)
-            denom = jnp.where(total > 0, total, 1.0)
-            agg = jax.tree_util.tree_map(
-                lambda g: jnp.tensordot(wvec, g, axes=(0, 0)) / denom, hat)
+            with jax.named_scope("fl_aggregate"):
+                total = jnp.sum(wvec)
+                denom = jnp.where(total > 0, total, 1.0)
+                agg = jax.tree_util.tree_map(
+                    lambda g: jnp.tensordot(wvec, g, axes=(0, 0)) / denom, hat)
             return algo.apply(params, aux, agg)
 
         @jax.jit
@@ -592,7 +592,7 @@ class AsyncRoundEngine(engine_lib.RoundEngine):
             member_np = idle.astype(np.float32)
             member = jnp.asarray(member_np)
             with tm.scope("sample"):
-                xb, yb = algo.sample(rng, self.client_x, self.client_y)
+                xb, yb = algo.sample(rng, self.client_x, self.client_y, tm)
             rnd = None
             agg = hat = None
             if driver is None:

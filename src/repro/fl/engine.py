@@ -84,6 +84,7 @@ __all__ = [
     "resolve_scenario",
     "resolve_downlink",
     "resolve_compression",
+    "cohort_mean",
     "dropout_weighted_mean",
     "record_link_round",
     "link_telemetry",
@@ -176,6 +177,13 @@ def resolve_compression(compression, driver):
     return None
 
 
+def cohort_mean(tree):
+    """Plain mean of ``(M, ...)`` leaves over the client axis: the layered
+    aggregate of a driver-less round."""
+    with jax.named_scope("fl_aggregate"):
+        return jax.tree_util.tree_map(lambda g: jnp.mean(g, axis=0), tree)
+
+
 def dropout_weighted_mean(tree, active):
     """Mean of ``(M, ...)`` leaves over active clients only.
 
@@ -183,9 +191,10 @@ def dropout_weighted_mean(tree, active):
     yields zeros (the global model simply does not move). Jit-safe — the
     shared aggregation rule of every scenario-driven round.
     """
-    denom = jnp.maximum(jnp.sum(active), 1.0)
-    return jax.tree_util.tree_map(
-        lambda g: jnp.tensordot(active, g, axes=(0, 0)) / denom, tree)
+    with jax.named_scope("fl_aggregate"):
+        denom = jnp.maximum(jnp.sum(active), 1.0)
+        return jax.tree_util.tree_map(
+            lambda g: jnp.tensordot(active, g, axes=(0, 0)) / denom, tree)
 
 
 def record_link_round(res: "FLResult", r: int, driver, stats, rnd,
@@ -279,14 +288,20 @@ class FedSGD:
         """Optimizer state threaded through the rounds."""
         return self.opt.init(params)
 
-    def sample(self, rng, client_x, client_y):
-        """One round's per-client minibatches: ``(M, B, ...)`` images/labels."""
+    def sample(self, rng, client_x, client_y,
+               tm=obs_timers_lib.NULL_TIMERS):
+        """One round's per-client minibatches: ``(M, B, ...)`` images/labels.
+
+        ``tm`` (the engine's phase sink) times the host gather (``gather``)
+        apart from the host-to-device copy (``h2d``)."""
         M = client_x.shape[0]
-        take = rng.integers(0, client_x.shape[1], (M, self.batch_per_round))
-        xb = jnp.asarray(
-            np.take_along_axis(client_x, take[:, :, None, None], axis=1))
-        yb = jnp.asarray(np.take_along_axis(client_y, take, axis=1))
-        return xb, yb
+        with tm.scope("gather"):
+            take = rng.integers(0, client_x.shape[1],
+                                (M, self.batch_per_round))
+            xb = np.take_along_axis(client_x, take[:, :, None, None], axis=1)
+            yb = np.take_along_axis(client_y, take, axis=1)
+        with tm.scope("h2d"):
+            return jnp.asarray(xb), jnp.asarray(yb)
 
     def payload(self, params, xb, yb):
         """Per-client gradients of the shared global model (error-free
@@ -294,12 +309,14 @@ class FedSGD:
         def client_grad(x, y):
             return self.grad_fn(params, x, y)
 
-        return jax.vmap(client_grad)(xb, yb)
+        with jax.named_scope("fl_grad"):
+            return jax.vmap(client_grad)(xb, yb)
 
     def payload_from(self, recv_params, xb, yb):
         """Per-client gradients at each client's *received* model copy (the
         noisy-downlink variant of :meth:`payload`)."""
-        return jax.vmap(self.grad_fn)(recv_params, xb, yb)
+        with jax.named_scope("fl_grad"):
+            return jax.vmap(self.grad_fn)(recv_params, xb, yb)
 
     def wrap_uplink(self, payload, transmit):
         """FedSGD uploads raw gradients — no transport-side scaling."""
@@ -307,7 +324,8 @@ class FedSGD:
 
     def apply(self, params, opt_state, agg):
         """PS update (eq. (6)): one optimizer step on the aggregate."""
-        return self.opt.update(agg, opt_state, params)
+        with jax.named_scope("fl_apply"):
+            return self.opt.update(agg, opt_state, params)
 
 
 class FedAvg:
@@ -347,19 +365,22 @@ class FedAvg:
         """FedAvg applies deltas directly — no optimizer state."""
         return None
 
-    def sample(self, rng, client_x, client_y):
-        """One round's batches: ``(M, local_steps, B, ...)`` images/labels."""
+    def sample(self, rng, client_x, client_y,
+               tm=obs_timers_lib.NULL_TIMERS):
+        """One round's batches: ``(M, local_steps, B, ...)`` images/labels,
+        timed as in :meth:`FedSGD.sample`."""
         M = client_x.shape[0]
         L, B = self.local_steps, self.batch_per_step
         sample_shape = client_x.shape[2:]
-        take = rng.integers(0, client_x.shape[1], (M, L, B))
-        xb = jnp.asarray(np.take_along_axis(
-            client_x, take.reshape(M, -1)[:, :, None, None], axis=1
-        ).reshape((M, L, B) + sample_shape))
-        yb = jnp.asarray(np.take_along_axis(
-            client_y, take.reshape(M, -1), axis=1
-        ).reshape(M, L, B))
-        return xb, yb
+        with tm.scope("gather"):
+            take = rng.integers(0, client_x.shape[1], (M, L, B))
+            xb = np.take_along_axis(
+                client_x, take.reshape(M, -1)[:, :, None, None], axis=1
+            ).reshape((M, L, B) + sample_shape)
+            yb = np.take_along_axis(
+                client_y, take.reshape(M, -1), axis=1).reshape(M, L, B)
+        with tm.scope("h2d"):
+            return jnp.asarray(xb), jnp.asarray(yb)
 
     def _local_delta(self, start, x, y):
         """One client's weight delta after ``local_steps`` SGD steps from
@@ -375,12 +396,15 @@ class FedAvg:
 
     def payload(self, params, xb, yb):
         """Per-client local-step deltas from the shared global model."""
-        return jax.vmap(lambda x, y: self._local_delta(params, x, y))(xb, yb)
+        with jax.named_scope("fl_grad"):
+            return jax.vmap(
+                lambda x, y: self._local_delta(params, x, y))(xb, yb)
 
     def payload_from(self, recv_params, xb, yb):
         """Per-client deltas, each relative to that client's *received*
         model copy — the PS still adds the mean delta to the true model."""
-        return jax.vmap(self._local_delta)(recv_params, xb, yb)
+        with jax.named_scope("fl_grad"):
+            return jax.vmap(self._local_delta)(recv_params, xb, yb)
 
     @staticmethod
     def _expand(s, like):
@@ -406,13 +430,16 @@ class FedAvg:
         cohort then rides the batched uplink unchanged."""
         if self.scale_mode != "max_abs":
             return transmit(deltas)
-        scale = self._compute_scale(deltas)
-        out, stats = transmit(self._div_scale(deltas, scale))
-        return self._mul_scale(out, scale), stats
+        with jax.named_scope("fl_uplink"):
+            scale = self._compute_scale(deltas)
+            out, stats = transmit(self._div_scale(deltas, scale))
+            return self._mul_scale(out, scale), stats
 
     def apply(self, params, aux, agg):
         """PS update: add the aggregated delta to the global model."""
-        return jax.tree_util.tree_map(lambda p, d: p + d, params, agg), aux
+        with jax.named_scope("fl_apply"):
+            return jax.tree_util.tree_map(
+                lambda p, d: p + d, params, agg), aux
 
 
 # -------------------------------------------------------------- round engine
@@ -689,7 +716,7 @@ class RoundEngine:
             hat, stats = algo.wrap_uplink(
                 payload,
                 lambda t: transport_lib.transmit_pytree_batch(t, key, tcfg))
-            agg = jax.tree_util.tree_map(lambda g: jnp.mean(g, axis=0), hat)
+            agg = cohort_mean(hat)
             params, aux = algo.apply(params, aux, agg)
             return params, aux, stats, dstats
 
@@ -744,16 +771,16 @@ class RoundEngine:
                     recv, dstats = transport_lib.transmit_pytree_broadcast(
                         params, key, self.dl_cfg, M)
                     payload = algo.payload_from(recv, xb, yb)
-                flat, spec = transport_lib._flatten_client_tree(payload)
-                vals, idx, residual = sparsify_lib.ef_select_batch(
-                    residual, flat, kbase, comp, _sel_keys(key))
-                hat_flat, stats = algo.wrap_uplink(
-                    vals,
-                    lambda v: framing_lib.transmit_sparse_batch(
-                        v, idx, D, key, tcfg, comp))
-                hat = transport_lib._unflatten_client_tree(hat_flat, spec)
-                agg = jax.tree_util.tree_map(
-                    lambda g: jnp.mean(g, axis=0), hat)
+                with jax.named_scope("fl_uplink"):
+                    flat, spec = transport_lib._flatten_client_tree(payload)
+                    vals, idx, residual = sparsify_lib.ef_select_batch(
+                        residual, flat, kbase, comp, _sel_keys(key))
+                    hat_flat, stats = algo.wrap_uplink(
+                        vals,
+                        lambda v: framing_lib.transmit_sparse_batch(
+                            v, idx, D, key, tcfg, comp))
+                    hat = transport_lib._unflatten_client_tree(hat_flat, spec)
+                agg = cohort_mean(hat)
                 params, aux = algo.apply(params, aux, agg)
                 return params, aux, stats, dstats, residual
 
@@ -761,8 +788,9 @@ class RoundEngine:
 
         @jax.jit
         def eval_acc(params):
-            return cnn.accuracy(params, jnp.asarray(self.test_x),
-                                jnp.asarray(self.test_y))
+            with jax.named_scope("fl_eval"):
+                return cnn.accuracy(params, jnp.asarray(self.test_x),
+                                    jnp.asarray(self.test_y))
 
         self._eval_acc = eval_acc
 
@@ -812,16 +840,18 @@ class RoundEngine:
                 else:
                     recv, dstats = self._broadcast_scenario(params, k_tx, rnd)
                     payload = algo.payload_from(recv, xb, yb)
-                flat, spec = transport_lib._flatten_client_tree(payload)
-                vals, idx, residual = sparsify_lib.ef_select_batch(
-                    residual, flat, kbase, comp, _sel_keys(k_tx),
-                    active=rnd.active)
-                hat_flat, stats = algo.wrap_uplink(
-                    vals,
-                    lambda v: framing_lib.transmit_sparse_batch_adaptive(
-                        v, idx, D, k_tx, select_mode_cfgs(driver), rnd.mode,
-                        comp, snr_db=rnd.snr_db, dispatch="select"))
-                hat = transport_lib._unflatten_client_tree(hat_flat, spec)
+                with jax.named_scope("fl_uplink"):
+                    flat, spec = transport_lib._flatten_client_tree(payload)
+                    vals, idx, residual = sparsify_lib.ef_select_batch(
+                        residual, flat, kbase, comp, _sel_keys(k_tx),
+                        active=rnd.active)
+                    hat_flat, stats = algo.wrap_uplink(
+                        vals,
+                        lambda v: framing_lib.transmit_sparse_batch_adaptive(
+                            v, idx, D, k_tx, select_mode_cfgs(driver),
+                            rnd.mode, comp, snr_db=rnd.snr_db,
+                            dispatch="select"))
+                    hat = transport_lib._unflatten_client_tree(hat_flat, spec)
                 agg = dropout_weighted_mean(hat, rnd.active)
                 params, aux = algo.apply(params, aux, agg)
                 return params, aux, stats, lstate, rnd, dstats, residual
@@ -853,15 +883,17 @@ class RoundEngine:
             # work, kernel rows allowed) around the jitted compute steps.
             k_link, k_tx = jax.random.split(key)
             lstate, rnd = link_round(lstate, prev_mode, prev_est, k_link)
-            mode_np = np.asarray(rnd.mode)
+            with self.phase_timers.scope("sync"):
+                mode_np = np.asarray(rnd.mode)
             dstats = None
             if dl is None:
                 payload = payload_shared(params, xb, yb)
             else:
                 dl_mode = None
                 if dl.adaptive:
-                    dl_mode = np.asarray(self._downlink_modes(
-                        np.asarray(rnd.est_db)))
+                    with self.phase_timers.scope("sync"):
+                        dl_mode = np.asarray(self._downlink_modes(
+                            np.asarray(rnd.est_db)))
                 recv, dstats = self._broadcast_scenario(
                     params, k_tx, rnd, dl_mode=dl_mode, dispatch="bucketed")
                 payload = payload_per_client(recv, xb, yb)
@@ -894,15 +926,17 @@ class RoundEngine:
                 # in mode order, and only the apply tail is jitted.
                 k_link, k_tx = jax.random.split(key)
                 lstate, rnd = link_round(lstate, prev_mode, prev_est, k_link)
-                mode_np = np.asarray(rnd.mode)
+                with self.phase_timers.scope("sync"):
+                    mode_np = np.asarray(rnd.mode)
                 dstats = None
                 if dl is None:
                     payload = payload_shared(params, xb, yb)
                 else:
                     dl_mode = None
                     if dl.adaptive:
-                        dl_mode = np.asarray(self._downlink_modes(
-                            np.asarray(rnd.est_db)))
+                        with self.phase_timers.scope("sync"):
+                            dl_mode = np.asarray(self._downlink_modes(
+                                np.asarray(rnd.est_db)))
                     recv, dstats = self._broadcast_scenario(
                         params, k_tx, rnd, dl_mode=dl_mode,
                         dispatch="bucketed")
@@ -937,24 +971,27 @@ class RoundEngine:
             # batch once, around the jitted compute steps.
             k_link, k_tx = jax.random.split(key)
             lstate, rnd = link_round(lstate, prev_mode, prev_est, k_link)
-            mode_np = np.asarray(rnd.mode)
+            with self.phase_timers.scope("sync"):
+                mode_np = np.asarray(rnd.mode)
             dstats = None
             if dl is None:
                 payload = payload_shared(params, xb, yb)
             else:
                 dl_mode = None
                 if dl.adaptive:
-                    dl_mode = np.asarray(self._downlink_modes(
-                        np.asarray(rnd.est_db)))
+                    with self.phase_timers.scope("sync"):
+                        dl_mode = np.asarray(self._downlink_modes(
+                            np.asarray(rnd.est_db)))
                 recv, dstats = self._broadcast_scenario(
                     params, k_tx, rnd, dl_mode=dl_mode, dispatch="bucketed")
                 payload = payload_per_client(recv, xb, yb)
-            flat, spec = transport_lib._flatten_client_tree(payload)
-            acc = accumulate(residual, flat)
-            dense_hat, stats, sent = self._sparse_bucketed_uplink(
-                acc, k_tx, mode_np, rnd.snr_db)
-            residual = residual_update(acc, sent, rnd.active)
-            hat = transport_lib._unflatten_client_tree(dense_hat, spec)
+            with jax.named_scope("fl_uplink"):
+                flat, spec = transport_lib._flatten_client_tree(payload)
+                acc = accumulate(residual, flat)
+                dense_hat, stats, sent = self._sparse_bucketed_uplink(
+                    acc, k_tx, mode_np, rnd.snr_db)
+                residual = residual_update(acc, sent, rnd.active)
+                hat = transport_lib._unflatten_client_tree(dense_hat, spec)
             params, aux = apply_update(params, aux, hat, rnd.active)
             return params, aux, stats, lstate, rnd, dstats, residual
 
@@ -1083,7 +1120,9 @@ class RoundEngine:
         force a device->host sync the dict view never paid), append the
         record, mirror its link-dict view, and write the ledger line."""
         if self.ledger is not None and stats is not None:
-            for name, value in stats.round_summary().items():
+            with self.phase_timers.scope("sync"):
+                summary = stats.round_summary()
+            for name, value in summary.items():
                 setattr(rec, name, value)
         res.records.append(rec)
         if rec.has_link_fields():
@@ -1129,7 +1168,7 @@ class RoundEngine:
         for r in range(self.n_rounds):
             key, rk = jax.random.split(key)
             with tm.scope("sample"):
-                xb, yb = algo.sample(rng, self.client_x, self.client_y)
+                xb, yb = algo.sample(rng, self.client_x, self.client_y, tm)
             rnd = None
             if driver is None:
                 with tm.scope("round"):
@@ -1169,15 +1208,21 @@ class RoundEngine:
                 self.prev_mode, self.prev_est = rnd.mode, rnd.est_db
                 with tm.scope("telemetry"):
                     per_client_air = driver.airtime(stats, rnd, timings)
-                    rec = obs_records_lib.scenario_round_record(
-                        r, rnd, per_client_air, len(driver.mode_cfgs))
-            cum_air += float(jnp.sum(per_client_air))
+                    with tm.scope("sync"):
+                        rec = obs_records_lib.scenario_round_record(
+                            r, rnd, per_client_air, len(driver.mode_cfgs))
+            # Every blocking device-to-host read of the round sits in a
+            # ``sync`` scope: the host waits for the device there.
+            with tm.scope("sync"):
+                cum_air += float(jnp.sum(per_client_air))
             if comp is not None:
-                self._compression_record(rec, stats, rnd)
+                with tm.scope("sync"):
+                    self._compression_record(rec, stats, rnd)
             if dstats is not None:
-                cum_air += self._downlink_air_record(rec, dstats)
+                with tm.scope("sync"):
+                    cum_air += self._downlink_air_record(rec, dstats)
             if self.sketcher is not None:
-                with tm.scope("telemetry"):
+                with tm.scope("telemetry"), tm.scope("sync"):
                     rec.sketches = self.sketcher.round_group(
                         rk, snr_db=rnd.snr_db, est_db=rnd.est_db,
                         ber=stats.client_metrics()["ber"],
@@ -1188,7 +1233,9 @@ class RoundEngine:
             self._finish_record(res, rec, stats)
             if r % self.eval_every == 0 or r == self.n_rounds - 1:
                 with tm.scope("eval"):
-                    acc = float(self._eval_acc(params))
+                    acc = self._eval_acc(params)
+                    with tm.scope("sync"):
+                        acc = float(acc)
                 res.rounds.append(r)
                 res.accuracy.append(acc)
                 res.airtime_s.append(cum_air)

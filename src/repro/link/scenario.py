@@ -243,18 +243,19 @@ class ScenarioDriver:
         behavior.
         """
         scen = self.scenario
-        k_dyn, k_est, k_drop, k_strag = jax.random.split(key, 4)
-        state, snr = dynamics_lib.step(state, k_dyn, scen.dynamics)
-        est = estimator_lib.step_estimate(snr, prev_est_db, k_est,
-                                          scen.estimator)
-        mode = policy_lib.choose_mode(est, prev_mode, scen.policy,
-                                      observed=observed)
-        shape = snr.shape
-        active = jax.random.bernoulli(
-            k_drop, 1.0 - scen.dropout_prob, shape).astype(jnp.float32)
-        straggler = jax.random.bernoulli(
-            k_strag, scen.straggler_prob, shape).astype(jnp.float32)
-        return state, LinkRound(snr, est, mode, active, straggler)
+        with jax.named_scope("fl_link"):
+            k_dyn, k_est, k_drop, k_strag = jax.random.split(key, 4)
+            state, snr = dynamics_lib.step(state, k_dyn, scen.dynamics)
+            est = estimator_lib.step_estimate(snr, prev_est_db, k_est,
+                                              scen.estimator)
+            mode = policy_lib.choose_mode(est, prev_mode, scen.policy,
+                                          observed=observed)
+            shape = snr.shape
+            active = jax.random.bernoulli(
+                k_drop, 1.0 - scen.dropout_prob, shape).astype(jnp.float32)
+            straggler = jax.random.bernoulli(
+                k_strag, scen.straggler_prob, shape).astype(jnp.float32)
+            return state, LinkRound(snr, est, mode, active, straggler)
 
     def airtime(self, stats: transport_lib.TxStats, rnd: LinkRound,
                 timings: latency_lib.PhyTimings) -> jax.Array:

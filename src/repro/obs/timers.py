@@ -2,10 +2,9 @@
 
 JAX wall-clock numbers are bimodal — the first call of a jitted function
 pays tracing + XLA compilation, every later call pays only execution — so a
-single mean/median over a run conflates two different quantities. Every
-benchmark in this repo needs the split (``benchmarks/common.timeit`` reports
-it per-measurement), and the FL engines need it *per phase* so a 100-round
-run can say "the bucketed uplink cost 80 µs steady after a 2.1 s compile".
+single mean/median over a run conflates two different quantities. The FL
+engines need the split *per phase*, so a 100-round run can say "the
+bucketed uplink cost 80 µs steady after a 2.1 s compile".
 
 :class:`PhaseTimers` keeps one :class:`PhaseStat` per named scope:
 
@@ -14,13 +13,20 @@ run can say "the bucketed uplink cost 80 µs steady after a 2.1 s compile".
         ...host work / dispatch...
     timers.summary()["uplink"]  # first_s vs steady_median_s
 
-Scopes measure *host* wall time between ``__enter__`` and ``__exit__``. JAX
-dispatch is asynchronous, so a scope that only enqueues device work charges
-the wait to whichever later scope blocks (in the engines: telemetry and
-eval, which pull values to the host). That is the honest accounting for a
-host-driven loop — the first call still captures trace+compile time, which
-is synchronous. ``NULL_TIMERS`` is a shared no-op sink so engine code can
-always write ``with self.phase_timers.scope(...)`` without branching.
+Scopes measure *host* wall time between ``__enter__`` and ``__exit__``, and
+each one is also a ``jax.profiler.TraceAnnotation``: under a live
+``jax.profiler`` trace the scopes land on the profiler's host plane, on the
+same clock as the device ops. JAX dispatch is asynchronous, so a scope that
+only enqueues device work returns before the work is done; the host waits
+for the device only where it reads a value back. The round engine puts
+every such blocking device-to-host read inside a ``sync`` scope, so the
+wait is charged to ``sync`` (nested in whichever scope holds the read), and
+``summary()["sync"]["calls"]`` counts the host syncs. The first call of a
+scope still captures trace + compile time, which is synchronous.
+
+``NULL_TIMERS`` is a shared no-op sink — its ``scope`` returns one shared
+``contextlib.nullcontext`` — so engine code can always write
+``with self.phase_timers.scope(...)`` without branching.
 """
 
 from __future__ import annotations
@@ -28,6 +34,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+
+import jax
 
 __all__ = ["PhaseStat", "PhaseTimers", "NULL_TIMERS", "resolve_timers"]
 
@@ -75,15 +83,17 @@ class PhaseTimers:
 
     @contextlib.contextmanager
     def scope(self, name: str):
-        """Context manager timing one occurrence of phase ``name``."""
+        """Context manager timing one occurrence of phase ``name`` and
+        marking it in the profiler's trace."""
         stat = self.phases.get(name)
         if stat is None:
             stat = self.phases[name] = PhaseStat(name)
-        t0 = time.perf_counter()
-        try:
-            yield stat
-        finally:
-            stat.record(time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield stat
+            finally:
+                stat.record(time.perf_counter() - t0)
 
     def summary(self) -> dict:
         """JSON-ready per-phase summary: calls, first (compile) seconds,
@@ -112,13 +122,15 @@ class PhaseTimers:
 
 
 class _NullTimers(PhaseTimers):
-    """Shared do-nothing sink: ``scope`` costs one context switch and
-    records nothing, so uninstrumented runs stay unperturbed."""
+    """Shared do-nothing sink: ``scope`` hands back one shared
+    ``nullcontext`` and records nothing, so uninstrumented runs stay
+    unperturbed."""
 
-    @contextlib.contextmanager
+    _NULL_SCOPE = contextlib.nullcontext()
+
     def scope(self, name: str):
         """No-op scope."""
-        yield None
+        return self._NULL_SCOPE
 
 
 NULL_TIMERS = _NullTimers()

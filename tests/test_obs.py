@@ -29,6 +29,8 @@ import dataclasses
 import json
 
 import golden_pre_engine as golden
+import jax
+import numpy as np
 import pytest
 
 from repro.compress.sparsify import CompressionConfig
@@ -84,18 +86,29 @@ def _full_arm_kw():
                 compression=CompressionConfig(method="topk", ratio=0.1))
 
 
-@pytest.fixture(scope="module")
-def sync_pair(cfg, world, tmp_path_factory):
-    """(instrumented run, bare twin, ledger path, timers) for the full
-    scenario+downlink+compression sync arm."""
+def _sync_pair(cfg, world, path, kw):
+    """(instrumented run, bare twin, ledger path, timers) of one sync arm."""
     cx, cy, ti, tl = world
-    path = str(tmp_path_factory.mktemp("obs") / "sync.jsonl")
     timers = PhaseTimers()
-    kw = _full_arm_kw()
     res = run_fl(cfg, _tc(), cx, cy, ti, tl, ledger=path,
                  phase_timers=timers, **kw)
     bare = run_fl(cfg, _tc(), cx, cy, ti, tl, **kw)
     return res, bare, path, timers
+
+
+@pytest.fixture(scope="module")
+def sync_pair(cfg, world, tmp_path_factory):
+    """The full scenario+downlink+compression sync arm."""
+    path = str(tmp_path_factory.mktemp("obs") / "sync.jsonl")
+    return _sync_pair(cfg, world, path, _full_arm_kw())
+
+
+@pytest.fixture(scope="module")
+def static_pair(cfg, world, tmp_path_factory):
+    """The driver-less fused round (no scenario), whose only host reads are
+    the airtime, the eval and the ledger's summaries."""
+    path = str(tmp_path_factory.mktemp("obs_static") / "static.jsonl")
+    return _sync_pair(cfg, world, path, dict(_KW, fused_aggregate=True))
 
 
 @pytest.fixture(scope="module")
@@ -128,6 +141,18 @@ def test_sync_sinks_are_neutral(sync_pair):
     assert res.airtime_s == bare.airtime_s
     assert res.final_accuracy == bare.final_accuracy
     assert res.link == bare.link
+
+
+def test_static_sinks_are_neutral(static_pair):
+    res, bare, _, timers = static_pair
+    assert res.accuracy == bare.accuracy
+    assert res.airtime_s == bare.airtime_s
+    for a, b in zip(jax.tree_util.tree_leaves(res.params),
+                    jax.tree_util.tree_leaves(bare.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # One airtime and one ledger summary a round, one read an eval.
+    assert timers.summary()["sync"]["calls"] == (2 * _KW["n_rounds"]
+                                                 + len(res.accuracy))
 
 
 def test_async_sinks_are_neutral(async_pair):
