@@ -592,7 +592,8 @@ class AsyncRoundEngine(engine_lib.RoundEngine):
             member_np = idle.astype(np.float32)
             member = jnp.asarray(member_np)
             with tm.scope("sample"):
-                xb, yb = algo.sample(rng, self.client_x, self.client_y, tm)
+                xb, yb = algo.sample(rng, self.client_rows, self.client_y,
+                                    tm)
             rnd = None
             agg = hat = None
             if driver is None:
