@@ -25,6 +25,7 @@ from repro.configs import phi35_moe_42b_a66b  # noqa: F401
 from repro.configs import qwen2_1_5b  # noqa: F401
 from repro.configs import deepseek_coder_33b  # noqa: F401
 from repro.configs import mnist_cnn  # noqa: F401
+from repro.configs import moonlight_16b_a3b  # noqa: F401
 
 ARCH_IDS = [
     "kimi-k2-1t-a32b",
@@ -37,4 +38,5 @@ ARCH_IDS = [
     "phi3.5-moe-42b-a6.6b",
     "qwen2-1.5b",
     "deepseek-coder-33b",
+    "moonlight-16b-a3b",
 ]

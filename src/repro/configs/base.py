@@ -25,6 +25,14 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: int = 0  # 0 = full causal attention (training variant)
     attn_impl: str = "naive"  # "naive" | "blockwise" (flash-style online softmax)
+    # | "per_sequence" (naive, one sequence at a time, recomputed in backward)
+    # latent attention (MLA, DeepSeek-V2/V3): on when kv_lora_rank > 0.
+    # q_lora_rank 0 projects the query straight from the hidden state.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     decode_window: int = 4096  # ring-buffer window used for long_500k decode
     # MoE
     n_experts: int = 0
@@ -33,9 +41,20 @@ class ModelConfig:
     moe_d_ff: int = 0
     dense_d_ff: int = 0  # FFN width of the leading dense layers (MoE models)
     first_dense_layers: int = 0
-    capacity_factor: float = 1.5
+    capacity_factor: float = 1.5  # <= 0: no limit (sigmoid-routed share only)
     aux_loss_coef: float = 0.01
     moe_impl: str = "dense"  # "dense" | "expert_parallel" (shard_map all_to_all)
+    # "softmax": softmax router, capacity dispatch, aux loss. "sigmoid": the
+    # DeepSeek-V3 noaux_tc router (top-k of sigmoid score + correction bias,
+    # weights the selected scores normalised and scaled by routed_scale).
+    router_score: str = "softmax"
+    router_bias_std: float = 0.0  # correction bias drawn N(0, std^2): a buffer
+    norm_topk_prob: bool = True
+    routed_scale: float = 1.0
+    # Expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of the router's n_experts (0 = all).
+    experts_held: int = 0
+    expert_offset: int = 0
     # SSM (mamba1)
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -54,11 +73,20 @@ class ModelConfig:
     tie_embeddings: bool = False
     scan_unroll: bool = False  # unroll layer scans (dry-run cost extraction)
     dtype: str = "bfloat16"
+    rms_eps: float = 1e-6
     source: str = ""  # citation
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def is_subquadratic(self) -> bool:
@@ -83,7 +111,11 @@ class ModelConfig:
         if self.n_experts:
             small.update(n_experts=4, top_k=min(self.top_k, 2), moe_d_ff=128,
                          dense_d_ff=256,
-                         first_dense_layers=min(self.first_dense_layers, 1))
+                         first_dense_layers=min(self.first_dense_layers, 1),
+                         experts_held=0, expert_offset=0)
+        if self.kv_lora_rank:
+            small.update(kv_lora_rank=32, q_lora_rank=48 if self.q_lora_rank else 0,
+                         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm_state:
             small.update(ssm_state=8)
         if self.attn_period:

@@ -116,6 +116,7 @@ __all__ = [
     "transmit_batch_adaptive",
     "transmit_pytree_batch_adaptive",
     "transmit_batch_aggregate",
+    "aggregate_words",
     "transmit_pytree_batch_aggregate",
     "transmit_batch_adaptive_aggregate",
     "transmit_pytree_batch_adaptive_aggregate",
@@ -550,8 +551,9 @@ def _batch_with_keys(x: jax.Array, keys: jax.Array, cfg: TransportConfig,
         x, keys, snr_vec)
 
 
-def _scan_weighted_sum(rows, weights, num_active=None):
-    """``sum_c weights[c] * rows[c]`` as a ``lax.scan`` over the client axis.
+def _scan_weighted_sum(rows, weights, num_active=None, acc=None):
+    """``acc + sum_c weights[c] * rows[c]`` as a ``lax.scan`` over the
+    client axis (``acc`` defaults to zero).
 
     The arithmetic contract of the fused path: one multiply + one add per
     client per element, in client order — the same shape as the Pallas
@@ -563,7 +565,8 @@ def _scan_weighted_sum(rows, weights, num_active=None):
     """
     w = jnp.asarray(weights, jnp.float32)
     rows = rows.astype(jnp.float32)
-    zero = jnp.zeros(rows.shape[1:], jnp.float32)
+    zero = (jnp.zeros(rows.shape[1:], jnp.float32) if acc is None
+            else jnp.asarray(acc, jnp.float32))
     if num_active is None:
         def body(acc, wx):
             wc, xc = wx
@@ -583,7 +586,7 @@ def _scan_weighted_sum(rows, weights, num_active=None):
 
 
 def _batch_aggregate_with_keys(x, keys, cfg, snr_vec, weights, *,
-                               num_active=None, donate=False):
+                               num_active=None, donate=False, acc=None):
     """Single-mode batch + weighted aggregation over explicit keys.
 
     The fused-round engine under :func:`transmit_batch_aggregate` and each
@@ -593,6 +596,7 @@ def _batch_aggregate_with_keys(x, keys, cfg, snr_vec, weights, *,
     :func:`_scan_weighted_sum` over the standard batch — bit-identical to
     the kernel accumulator by the scan contract. ``weights`` are applied as
     given (normalize first: :func:`repro.core.aggregation.normalize_weights`).
+    ``acc`` is a running ``(N,)`` aggregate the sum starts from.
     Returns ``(agg (N,) float32, stats)`` with per-client ``(C,)`` stats.
     """
     if cfg.mode in ("naive", "approx") and cfg.use_kernel:
@@ -600,9 +604,9 @@ def _batch_aggregate_with_keys(x, keys, cfg, snr_vec, weights, *,
 
         return kernel_ops.approx_channel_transmit_batch_aggregate(
             x, keys, cfg, snr_vec, weights, num_active=num_active,
-            donate=donate and _donation_supported())
+            donate=donate and _donation_supported(), acc=acc)
     x_hat, stats = _batch_with_keys(x, keys, cfg, snr_vec)
-    return _scan_weighted_sum(x_hat, weights, num_active), stats
+    return _scan_weighted_sum(x_hat, weights, num_active, acc), stats
 
 
 def _same_channel(a: channel_lib.ChannelConfig,
@@ -1037,9 +1041,20 @@ def transmit_batch_adaptive(x: jax.Array, key: jax.Array,
     return x_hat, stats
 
 
+def aggregate_words(n: int, cfg: TransportConfig) -> int:
+    """The length a running aggregate of an ``n``-float payload is best
+    carried at between :func:`transmit_batch_aggregate` calls: the kernel's
+    padded length on the kernel path, ``n`` elsewhere."""
+    if cfg.mode in ("naive", "approx") and cfg.use_kernel:
+        from repro.kernels import ops as kernel_ops
+
+        return kernel_ops.padded_words(n)
+    return n
+
+
 def transmit_batch_aggregate(x: jax.Array, key: jax.Array,
                              cfg: TransportConfig, weights, *, snr_db=None,
-                             client_offset=0, donate: bool = False):
+                             client_offset=0, donate: bool = False, acc=None):
     """Fused uplink + aggregation: ``sum_c weights[c] * x_hat[c]`` in one pass.
 
     The hot-path twin of :func:`transmit_batch` followed by
@@ -1061,6 +1076,11 @@ def transmit_batch_aggregate(x: jax.Array, key: jax.Array,
         first (``fedsgd_aggregate_batch`` normalizes the same way).
       donate: release the ``x`` buffer into the launch on backends that
         honour donation (the uplink payload is dead after transmission).
+      acc: optional float32 aggregate of the clients before these (a wave
+        of a streamed cohort): the sum continues from it, in client order,
+        so streaming in waves gives the one-launch sum bit for bit. Of
+        length ``N``, or :func:`aggregate_words` ``(N, cfg)``, which comes
+        back at that length.
 
     Returns:
       ``(agg, stats)``: the ``(N,)`` float32 weighted aggregate and
@@ -1075,7 +1095,7 @@ def transmit_batch_aggregate(x: jax.Array, key: jax.Array,
     snr_vec = _resolve_batch_snr(cfg, num_clients, snr_db)
     keys = client_keys(key, num_clients, client_offset)
     return _batch_aggregate_with_keys(x, keys, cfg, snr_vec, weights,
-                                      donate=donate)
+                                      donate=donate, acc=acc)
 
 
 def transmit_batch_adaptive_aggregate(x: jax.Array, key: jax.Array, cfgs,

@@ -71,7 +71,7 @@ from repro.core import aggregation as aggregation_lib
 from repro.core import keylanes
 from repro.core import latency as latency_lib
 from repro.core import transport as transport_lib
-from repro.fl import cnn
+from repro.fl import payload as payload_lib
 from repro.obs import ledger as obs_ledger_lib
 from repro.obs import metrics as obs_metrics_lib
 from repro.obs import records as obs_records_lib
@@ -90,6 +90,7 @@ __all__ = [
     "device_shards",
     "sample_minibatches",
     "dropout_weighted_mean",
+    "wave_clients",
     "record_link_round",
     "link_telemetry",
     "select_mode_cfgs",
@@ -324,6 +325,34 @@ def sample_minibatches(rng, client_x, client_y, shape, image_shape,
                             image_shape)
 
 
+# -------------------------------------------------------------- cohort waves
+
+# A fused round streams its cohort through the uplink in waves when the
+# cohort's f32 payload would take more than this share of device memory.
+# The share is assumed, not measured: a wave also holds the gradient's
+# activations and the flat payload's copy, and one 568M-float client a wave
+# peaks at 4.65 GB of a 16 GB v5e; no larger wave has been measured.
+WAVE_MEMORY_SHARE = 1 / 8
+
+
+def wave_clients(num_clients: int, payload_floats: int,
+                 bytes_limit: int | None = None) -> int:
+    """Clients per uplink wave of a fused round: the whole cohort when its
+    f32 payload fits in ``WAVE_MEMORY_SHARE`` of the device's memory
+    (``bytes_limit``, read from the device when not given; no limit known
+    means one wave), else the largest divisor of the cohort that fits, and
+    at least 1."""
+    if bytes_limit is None:
+        bytes_limit = (jax.devices()[0].memory_stats() or {}).get(
+            "bytes_limit")
+    if not bytes_limit:
+        return num_clients
+    budget = bytes_limit * WAVE_MEMORY_SHARE
+    fits = [w for w in range(1, num_clients + 1)
+            if num_clients % w == 0 and w * payload_floats * 4 <= budget]
+    return max(fits, default=1)
+
+
 # --------------------------------------------------------------- algorithms
 
 
@@ -331,20 +360,23 @@ class FedSGD:
     """The paper's algorithm: one gradient per client per round (eq. (4)-(6)).
 
     Payload = the stacked per-client single-step gradients; the PS applies
-    the (dropout-weighted) mean through the SGD optimizer.
+    the (dropout-weighted) mean through the SGD optimizer. ``cfg`` is the
+    payload model (:mod:`repro.fl.payload`), or a CNN config for the
+    paper's CNN.
     """
 
     name = "fedsgd"
 
     def __init__(self, cfg, batch_per_round: int = 32):
         self.cfg = cfg
+        self.model = payload_lib.payload_model(cfg)
         self.batch_per_round = batch_per_round
-        self.opt = make_sgd(cfg.lr)
-        self.grad_fn = jax.grad(cnn.loss_fn)
+        self.opt = make_sgd(self.model.lr)
+        self.grad_fn = jax.grad(self.model.loss, has_aux=True)
 
     def init_params(self, key):
         """Global model at round 0."""
-        return cnn.init_params(key, self.cfg)
+        return self.model.init(key)
 
     def init_opt(self, params):
         """Optimizer state threaded through the rounds."""
@@ -356,26 +388,34 @@ class FedSGD:
 
     def sample(self, rng, client_x, client_y,
                tm=obs_timers_lib.NULL_TIMERS):
-        """One round's per-client minibatches: ``(M, B, 28, 28)`` images and
-        ``(M, B)`` labels, on the device (see :func:`sample_minibatches`)."""
+        """One round's per-client minibatches: ``(M, B, *sample_shape)``
+        samples and ``(M, B)`` labels, on the device (see
+        :func:`sample_minibatches`)."""
         return sample_minibatches(rng, client_x, client_y,
                                   self.draw_shape(client_y.shape[0]),
-                                  (self.cfg.image_size,) * 2, tm)
+                                  self.model.sample_shape, tm)
 
-    def payload(self, params, xb, yb):
+    def payload_counted(self, params, xb, yb):
         """Per-client gradients of the shared global model (error-free
-        downlink): leaves ``(M, ...)``."""
+        downlink), leaves ``(M, ...)``, and the payload model's counters
+        summed over the clients."""
         def client_grad(x, y):
             return self.grad_fn(params, x, y)
 
         with jax.named_scope("fl_grad"):
-            return jax.vmap(client_grad)(xb, yb)
+            grads, counters = jax.vmap(client_grad)(xb, yb)
+            return grads, jax.tree_util.tree_map(
+                lambda c: jnp.sum(c, axis=0), counters)
+
+    def payload(self, params, xb, yb):
+        """:meth:`payload_counted` without the counters."""
+        return self.payload_counted(params, xb, yb)[0]
 
     def payload_from(self, recv_params, xb, yb):
         """Per-client gradients at each client's *received* model copy (the
         noisy-downlink variant of :meth:`payload`)."""
         with jax.named_scope("fl_grad"):
-            return jax.vmap(self.grad_fn)(recv_params, xb, yb)
+            return jax.vmap(self.grad_fn)(recv_params, xb, yb)[0]
 
     def wrap_uplink(self, payload, transmit):
         """FedSGD uploads raw gradients — no transport-side scaling."""
@@ -406,10 +446,11 @@ class FedAvg:
     def __init__(self, cfg, local_steps: int = 4, batch_per_step: int = 32,
                  scale_mode: str = "none"):
         self.cfg = cfg
+        self.model = payload_lib.payload_model(cfg)
         self.local_steps = local_steps
         self.batch_per_step = batch_per_step
         self.scale_mode = scale_mode
-        self.grad_fn = jax.grad(cnn.loss_fn)
+        self.grad_fn = jax.grad(self.model.loss, has_aux=True)
         # jitted so the host-driven bucketed round doesn't run the scale math
         # op-by-op; inside a fused round's trace they simply inline.
         self._compute_scale = jax.jit(self._scale_of)
@@ -418,7 +459,7 @@ class FedAvg:
 
     def init_params(self, key):
         """Global model at round 0."""
-        return cnn.init_params(key, self.cfg)
+        return self.model.init(key)
 
     def init_opt(self, params):
         """FedAvg applies deltas directly — no optimizer state."""
@@ -430,19 +471,20 @@ class FedAvg:
 
     def sample(self, rng, client_x, client_y,
                tm=obs_timers_lib.NULL_TIMERS):
-        """One round's batches: ``(M, local_steps, B, 28, 28)`` images and
-        ``(M, local_steps, B)`` labels, as in :meth:`FedSGD.sample`."""
+        """One round's batches: ``(M, local_steps, B, *sample_shape)``
+        samples and ``(M, local_steps, B)`` labels, as in
+        :meth:`FedSGD.sample`."""
         return sample_minibatches(rng, client_x, client_y,
                                   self.draw_shape(client_y.shape[0]),
-                                  (self.cfg.image_size,) * 2, tm)
+                                  self.model.sample_shape, tm)
 
     def _local_delta(self, start, x, y):
         """One client's weight delta after ``local_steps`` SGD steps from
         ``start`` (its received copy of the global model)."""
         def body(p, inp):
             xi, yi = inp
-            g = self.grad_fn(p, xi, yi)
-            p = jax.tree_util.tree_map(lambda a, b: a - self.cfg.lr * b, p, g)
+            g, _ = self.grad_fn(p, xi, yi)
+            p = jax.tree_util.tree_map(lambda a, b: a - self.model.lr * b, p, g)
             return p, None
 
         local, _ = jax.lax.scan(body, start, (x, y))
@@ -557,6 +599,8 @@ class RoundEngine:
         key, pk = jax.random.split(key)
         self.params = algorithm.init_params(pk)
         self.aux = algorithm.init_opt(self.params)
+        self.payload_floats = sum(
+            l.size for l in jax.tree_util.tree_leaves(self.params))
         self.driver = resolve_scenario(scenario, transport_cfg)
         if adaptive_dispatch not in ("bucketed", "select"):
             raise ValueError(
@@ -601,8 +645,7 @@ class RoundEngine:
         self._comp_dim = self._comp_k = 0
         if self.compression is not None:
             comp = self.compression
-            self._comp_dim = int(sum(
-                l.size for l in jax.tree_util.tree_leaves(self.params)))
+            self._comp_dim = self.payload_floats
             self._comp_k = sparsify_lib.resolve_k(comp, self._comp_dim)
             if self.driver is not None:
                 from repro.link import policy as policy_lib
@@ -653,6 +696,15 @@ class RoundEngine:
                     "fused_aggregate=True needs adaptive_dispatch="
                     "'bucketed' for scenario runs — the select lowering "
                     "has no kernel rows to fuse into")
+
+        # A fused driver-less round without a downlink leg streams a cohort
+        # too large for the device in waves (``wave_clients``): the uplink
+        # launches per round, ``uplink_waves``.
+        self.wave = self.num_clients
+        if (self.fused_aggregate and self.driver is None
+                and self.downlink is None):
+            self.wave = wave_clients(self.num_clients, self.payload_floats)
+        self.uplink_waves = self.num_clients // self.wave
 
         self._build_round_fns()
         if self.driver is not None:
@@ -786,7 +838,7 @@ class RoundEngine:
                 lambda t: transport_lib.transmit_pytree_batch(t, key, tcfg))
             agg = cohort_mean(hat)
             params, aux = algo.apply(params, aux, agg)
-            return params, aux, stats, dstats
+            return params, aux, stats, dstats, {}
 
         self._round_step = round_step
 
@@ -805,17 +857,19 @@ class RoundEngine:
             def round_step_fused(params, aux, xb, yb, key):
                 # Driver-less fused round: modulate -> channel -> demap ->
                 # accumulate in one transport pass; no per-client hat tree.
-                dstats = None
+                dstats, counters = None, {}
                 if dl is None:
-                    payload = algo.payload(params, xb, yb)
+                    agg, stats, counters = self._fused_uplink(
+                        params, xb, yb, key, uniform_w)
                 else:
                     recv, dstats = transport_lib.transmit_pytree_broadcast(
                         params, key, self.dl_cfg, M)
                     payload = algo.payload_from(recv, xb, yb)
-                agg, stats = transport_lib.transmit_pytree_batch_aggregate(
-                    payload, key, tcfg, uniform_w, donate=True)
+                    agg, stats = \
+                        transport_lib.transmit_pytree_batch_aggregate(
+                            payload, key, tcfg, uniform_w, donate=True)
                 params, aux = algo.apply(params, aux, agg)
-                return params, aux, stats, dstats
+                return params, aux, stats, dstats, counters
 
             self._round_step = round_step_fused
 
@@ -857,8 +911,8 @@ class RoundEngine:
         @jax.jit
         def eval_acc(params):
             with jax.named_scope("fl_eval"):
-                return cnn.accuracy(params, jnp.asarray(self.test_x),
-                                    jnp.asarray(self.test_y))
+                return algo.model.evaluate(params, jnp.asarray(self.test_x),
+                                           jnp.asarray(self.test_y))
 
         self._eval_acc = eval_acc
 
@@ -1065,6 +1119,54 @@ class RoundEngine:
 
         self._round_step_link_bucketed_comp = round_step_link_bucketed_comp
 
+    def _fused_uplink(self, params, xb, yb, key, weights):
+        """Payload, uplink and aggregate of a fused driver-less round:
+        ``(agg tree, stats, counters)``.
+
+        With one wave the cohort's payload goes through one kernel launch.
+        Otherwise a ``lax.scan`` over waves of ``self.wave`` clients
+        computes each wave's payload, flattens it, and folds it into the
+        running aggregate; wave ``i``'s clients keep the keys of clients
+        ``i * wave ..`` of one launch and the sum runs in client order, so
+        the same payload gives the one-launch sum bit for bit (a gradient
+        batched over fewer clients may itself round differently)."""
+        algo, tcfg, W = self.algo, self.transport_cfg, self.wave
+        M = xb.shape[0]
+        if W >= M:
+            payload, counters = algo.payload_counted(params, xb, yb)
+            agg, stats = transport_lib.transmit_pytree_batch_aggregate(
+                payload, key, tcfg, weights, donate=True)
+            return agg, stats, counters
+
+        def waves(a):
+            return a.reshape((M // W, W) + a.shape[1:])
+
+        shapes = jax.eval_shape(algo.payload, params, xb[:W], yb[:W])
+        leaves, treedef = jax.tree_util.tree_flatten(shapes)
+        spec = (leaves, treedef, [l.size // W for l in leaves])
+
+        def wave(acc, inp):
+            i, x, y, w = inp
+            payload, counters = algo.payload_counted(params, x, y)
+            flat, _ = transport_lib._flatten_client_tree(payload)
+            acc, stats = transport_lib.transmit_batch_aggregate(
+                flat, key, tcfg, w, client_offset=i * W, donate=True,
+                acc=acc)
+            return acc, (stats, counters)
+
+        acc0 = jnp.zeros((transport_lib.aggregate_words(
+            self.payload_floats, tcfg),), jnp.float32)
+        with jax.named_scope("fl_uplink"):
+            acc, (stats, counters) = jax.lax.scan(
+                wave, acc0,
+                (jnp.arange(M // W), waves(xb), waves(yb), waves(weights)))
+            stats = jax.tree_util.tree_map(
+                lambda a: a.reshape((M,) + a.shape[2:]), stats)
+            counters = jax.tree_util.tree_map(
+                lambda c: jnp.sum(c, axis=0), counters)
+            return (transport_lib._unflatten_aggregate_tree(acc, spec),
+                    stats, counters)
+
     def _sparse_bucketed_uplink(self, acc, key, mode_np, snr_db):
         """Per-mode-budget sparse uplink over host-side mode buckets.
 
@@ -1164,6 +1266,7 @@ class RoundEngine:
             "dispatch": self.dispatch,
             "transport_mode": self.transport_cfg.mode,
             "sample_h2d_bytes": self.sample_h2d_bytes,
+            "uplink_waves": self.uplink_waves,
         }
         if scen is not None:
             from repro.link import policy as policy_lib
@@ -1238,12 +1341,12 @@ class RoundEngine:
             key, rk = jax.random.split(key)
             with tm.scope("sample"):
                 xb, yb = algo.sample(rng, self.client_rows, self.client_y, tm)
-            rnd = None
+            rnd, counters = None, {}
             if driver is None:
                 with tm.scope("round"):
                     if comp is None:
-                        params, aux, stats, dstats = self._round_step(
-                            params, aux, xb, yb, rk)
+                        params, aux, stats, dstats, counters = \
+                            self._round_step(params, aux, xb, yb, rk)
                     else:
                         (params, aux, stats, dstats,
                          self._ef_residual) = self._round_step_comp(
@@ -1281,9 +1384,14 @@ class RoundEngine:
                         rec = obs_records_lib.scenario_round_record(
                             r, rnd, per_client_air, len(driver.mode_cfgs))
             # Every blocking device-to-host read of the round sits in a
-            # ``sync`` scope: the host waits for the device there.
+            # ``sync`` scope: the host waits for the device there. The
+            # payload's counters come back with the airtime, in one read.
             with tm.scope("sync"):
-                cum_air += float(jnp.sum(per_client_air))
+                air, counters = jax.device_get(
+                    (jnp.sum(per_client_air), counters))
+                cum_air += float(air)
+            if counters:
+                rec.counters = {k: int(v) for k, v in counters.items()}
             if comp is not None:
                 with tm.scope("sync"):
                     self._compression_record(rec, stats, rnd)
@@ -1300,6 +1408,9 @@ class RoundEngine:
                         downlink_ber=(None if dstats is None
                                       else dstats.ber))
             self._finish_record(res, rec, stats)
+            # The engine holds the newest model only: a payload of GBs
+            # keeps no stale copy on the device.
+            self.params, self.aux = params, aux
             if r % self.eval_every == 0 or r == self.n_rounds - 1:
                 with tm.scope("eval"):
                     acc = self._eval_acc(params)

@@ -9,12 +9,12 @@ each intermediate through HBM:
 
 i.e. >= 36 B of HBM traffic per 4 B gradient at QPSK — memory-bound by 9x
 more traffic than necessary. This kernel fuses the whole chain inside one
-VMEM tile: 4 B in, 4 B out, plus a 4 B/tile error counter. Channel noise and
-Rayleigh fading are generated *inside* the kernel from a counter-based RNG
-(murmur3-finalizer hash + Box-Muller over the global symbol index), so no
-randomness is streamed from HBM. On real TPUs ``pltpu.prng_random_bits``
-could replace the hash; we keep the hash so interpret-mode CPU validation is
-bit-exact against the oracle.
+VMEM tile: 4 B in, 4 B out, plus one error-counter block per launch.
+Channel noise and Rayleigh fading are generated *inside* the kernel from a
+counter-based RNG (murmur3-finalizer hash + Box-Muller over the global
+symbol index), so no randomness is streamed from HBM. On real TPUs
+``pltpu.prng_random_bits`` could replace the hash; we keep the hash so
+interpret-mode CPU validation is bit-exact against the oracle.
 
 Tiling: the ``(C, N)`` payload is viewed as ``(C, N / 128, 128)`` and cut
 into ``(block_words / 128, 128)`` tiles (default 1024 words = 8 sublanes x
@@ -30,13 +30,33 @@ widened and narrowed by XLA around the launch, so Mosaic sees 32-bit
 vectors only (the bf16 wire then moves 4 B per word through the kernel, as
 the f32 wire does).
 
-Per-client seed / noise / gain (and aggregation weight) are scalar-prefetch
+The RNG counter is the symbol's index in its client's payload. Its low 32
+bits feed the hash, and each further 2^32 symbols (268M float32 words at
+QPSK) is a segment whose seed is the client's seed folded with the segment
+number (``ref.segment_seed``); segment 0 keeps the client's seed, so a
+payload below 2^32 symbols draws the stream it always did. A tile holds a
+power-of-two number of symbols and never straddles a segment, so the seed
+is picked per tile from a ``(C, segments)`` scalar table, with no work per
+symbol.
+
+Per-client seeds / noise / gain (and aggregation weight) are scalar-prefetch
 operands in SMEM, indexed by the client grid coordinate. Bit-error counters
-are lane-dense: one ``(rows, 128)`` int32 block per tile holds every
-client's count for that tile (client ``c`` at flat position ``c``), so the
-counter output is ``tiles x C`` words in HBM and never touches SMEM. The
-tile axis is ``"parallel"``; the client axis is ``"arbitrary"`` because the
-counter block (and the fused kernel's accumulator) is revisited across it.
+are lane-dense ``(rows, 128)`` int32 blocks, client ``c``'s count at flat
+position ``c``. A launch whose per-tile blocks stay under
+``PER_TILE_COUNTER_BYTES`` writes one block a tile (``tiles x C`` words in
+HBM, summed after the launch); its tile axis is ``"parallel"`` and its
+client axis ``"arbitrary"``, because the counter block (and the fused
+kernel's accumulator) is revisited across the clients. A larger payload
+(the per-tile blocks of a 568M-float payload would take 2.27 GB) keeps one
+block resident in VMEM for the whole grid and sums the tiles into it, so
+its counter output is ``C`` words; both its grid axes are then
+``"arbitrary"``.
+
+The fused kernel's accumulator starts at zero, or at a running aggregate
+passed in and aliased to the output: a round that streams its cohort in
+waves folds each wave into the aggregate of the waves before it, in client
+order, so any wave size gives one launch's sum of the same payload bit for
+bit.
 """
 
 from __future__ import annotations
@@ -99,16 +119,21 @@ def approx_channel_pallas(
 
 def _phy_tile(tile, client, seed_ref, noise_ref, gain_ref, x_ref, *,
               bits_per_symbol: int, fading: str, fade_block: int,
-              clamp_mask: int, block_words: int, word_bits: int):
+              clamp_mask: int, block_words: int, word_bits: int,
+              n_segments: int, tile_shift: int):
     """``(u, u_hat)`` words of one (client, tile): sent and received-and-
     clamped. The symbol counter restarts per client and the RNG is keyed by
-    the client's own seed, so each client reproduces the single-client
-    kernel's stream bit-for-bit."""
+    the client's own seed (its segment's, past 2^32 symbols), so each
+    client reproduces the single-client kernel's stream bit-for-bit."""
     s_per_word = word_bits // bits_per_symbol
     u = x_ref[...]
+    if n_segments > 1:
+        seed = seed_ref[client * n_segments + (tile >> tile_shift)]
+    else:
+        seed = seed_ref[client]
     u_hat = _ref.channel_tile(
         u,
-        seed_ref[client],
+        seed,
         tile * (block_words * s_per_word),
         noise_ref[client],
         gain_ref[client],
@@ -120,7 +145,13 @@ def _phy_tile(tile, client, seed_ref, noise_ref, gain_ref, x_ref, *,
     return u, u_hat & _U32(clamp_mask)
 
 
+# The largest per-tile counter output (``tiles x rows x 128`` int32) a
+# launch writes; past it the counter is one block summed over the tiles.
+PER_TILE_COUNTER_BYTES = 64 << 20
+
+
 def _make_kernel(aggregate: bool, masked: bool, *, valid_words: int,
+                 accumulate: bool = False, resident_counter: bool = False,
                  **params):
     """Grid body over ``(tiles, clients)``, client axis innermost.
 
@@ -135,7 +166,10 @@ def _make_kernel(aggregate: bool, masked: bool, *, valid_words: int,
     ``masked`` adds a leading ``num_active`` scalar: clients at or beyond it
     skip the PHY chain, count no errors, and write zeros (batch) or leave
     the accumulator untouched (aggregate) — the partial-batch grid the
-    adaptive dispatch's padded buckets ride.
+    adaptive dispatch's padded buckets ride. ``accumulate`` starts the
+    accumulator from an aggregate input (aliased to the output) instead of
+    zero. ``resident_counter`` sums every tile's counts into one block
+    zeroed at the first grid step, instead of one block a tile.
     """
     block_words = params["block_words"]
 
@@ -143,17 +177,26 @@ def _make_kernel(aggregate: bool, masked: bool, *, valid_words: int,
         refs = list(refs)
         na_ref = refs.pop(0) if masked else None
         w_ref = refs.pop(0) if aggregate else None
+        acc_ref = refs.pop(4) if accumulate else None
         seed_ref, noise_ref, gain_ref, x_ref, out_ref, err_ref = refs
         # Grid coordinates are read here, outside any pl.when branch, where
         # the interpret-mode evaluator can resolve them.
         tile = pl.program_id(0)
         client = pl.program_id(1)
 
-        @pl.when(client == 0)
+        first = client == 0
+        if resident_counter:
+            first = jnp.logical_and(tile == 0, first)
+
+        @pl.when(first)
         def _():
             err_ref[...] = jnp.zeros_like(err_ref)
-            if aggregate:
-                out_ref[...] = jnp.zeros_like(out_ref)
+
+        if aggregate:
+            @pl.when(client == 0)
+            def _():
+                out_ref[...] = (acc_ref[...] if accumulate
+                                else jnp.zeros_like(out_ref))
 
         def transmit():
             u, u_hat = _phy_tile(tile, client, seed_ref, noise_ref, gain_ref,
@@ -169,7 +212,11 @@ def _make_kernel(aggregate: bool, masked: bool, *, valid_words: int,
             flips = jnp.where(gidx < valid_words, _ref.bit_flips(u, u_hat), 0)
             count = jnp.sum(flips)
             slot = _ref.tile_word_index(err_ref.shape)
-            err_ref[...] = jnp.where(slot == client, count, err_ref[...])
+            if resident_counter:
+                err_ref[...] = err_ref[...] + jnp.where(slot == client,
+                                                        count, 0)
+            else:
+                err_ref[...] = jnp.where(slot == client, count, err_ref[...])
 
         if not masked:
             transmit()
@@ -186,11 +233,12 @@ def _make_kernel(aggregate: bool, masked: bool, *, valid_words: int,
 
 def _uplink_call(x, seeds, noise_powers, large_scale_gains, weights, *,
                  bits_per_symbol, fading, fade_block, clamp_mask, block_words,
-                 word_bits, valid_words, interpret, num_active):
+                 word_bits, valid_words, interpret, num_active, acc=None):
     """Shared launch of both kernels; ``weights=None`` is the batch kernel.
 
     Returns ``(out, bit_errors (C,) int32)`` where ``out`` is the demapped
-    ``(C, N)`` wire payload, or the ``(N,)`` f32 weighted sum.
+    ``(C, N)`` wire payload, or the ``(N,)`` f32 weighted sum (added to
+    ``acc`` when given).
     """
     c, n = x.shape
     if n % block_words or block_words % _ref.LANES:
@@ -201,21 +249,31 @@ def _uplink_call(x, seeds, noise_powers, large_scale_gains, weights, *,
     tiles = n // block_words
     aggregate = weights is not None
     masked = num_active is not None
+    accumulate = acc is not None
+    n_segments, tile_shift = _ref.segments(
+        tiles, block_words, word_bits // bits_per_symbol, fading, fade_block)
+    # Lane-dense counters: client c of a tile at flat slot c of its block.
+    err_rows = 8 * pl.cdiv(c, 8 * _ref.LANES)
+    resident = tiles * err_rows * _ref.LANES * 4 > PER_TILE_COUNTER_BYTES
     kernel = _make_kernel(
-        aggregate, masked,
+        aggregate, masked, accumulate=accumulate, resident_counter=resident,
         valid_words=n if valid_words is None else valid_words,
         bits_per_symbol=bits_per_symbol, fading=fading, fade_block=fade_block,
-        clamp_mask=clamp_mask, block_words=block_words, word_bits=word_bits)
+        clamp_mask=clamp_mask, block_words=block_words, word_bits=word_bits,
+        n_segments=n_segments, tile_shift=tile_shift)
 
-    scalars = [seeds.reshape(c).astype(_U32),
+    seeds = seeds.reshape(c).astype(_U32)
+    if n_segments > 1:
+        seeds = _ref.segment_seed(
+            seeds[:, None], jnp.arange(n_segments, dtype=jnp.int32)[None, :]
+        ).reshape(-1)
+    scalars = [seeds,
                noise_powers.reshape(c).astype(jnp.float32),
                large_scale_gains.reshape(c).astype(jnp.float32)]
     if aggregate:
         scalars.insert(0, weights.reshape(c).astype(jnp.float32))
     if masked:
         scalars.insert(0, jnp.reshape(jnp.asarray(num_active, jnp.int32), (1,)))
-    # Lane-dense counters: client c of a tile at flat slot c of its block.
-    err_rows = 8 * pl.cdiv(c, 8 * _ref.LANES)
     # Inside shard_map the outputs vary over every mesh axis an operand does.
     vma = frozenset().union(*(jax.typeof(a).vma for a in (x, *scalars)))
 
@@ -229,29 +287,45 @@ def _uplink_call(x, seeds, noise_powers, large_scale_gains, weights, *,
         out_spec = payload
         out_shape = jax.ShapeDtypeStruct((c, n // _ref.LANES, _ref.LANES),
                                          _U32, vma=vma)
+    operands = [_ref.wire_words(x, word_bits).reshape(c, n // _ref.LANES,
+                                                      _ref.LANES)]
+    in_specs = [payload]
+    aliases = {}
+    if accumulate:
+        operands.append(acc.astype(jnp.float32).reshape(n // _ref.LANES,
+                                                        _ref.LANES))
+        in_specs.append(out_spec)
+        aliases = {len(scalars) + 1: 0}
+    if resident:
+        err_spec = pl.BlockSpec((err_rows, _ref.LANES),
+                                lambda ti, ci, *_: (0, 0))
+        err_shape = (err_rows, _ref.LANES)
+        semantics = ("arbitrary", "arbitrary")
+    else:
+        err_spec = pl.BlockSpec((None, err_rows, _ref.LANES),
+                                lambda ti, ci, *_: (ti, 0, 0))
+        err_shape = (tiles, err_rows, _ref.LANES)
+        semantics = ("parallel", "arbitrary")
     out, errs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(tiles, c),
-            in_specs=[payload],
-            out_specs=[
-                out_spec,
-                pl.BlockSpec((None, err_rows, _ref.LANES),
-                             lambda ti, ci, *_: (ti, 0, 0)),
-            ],
+            in_specs=in_specs,
+            out_specs=[out_spec, err_spec],
         ),
         out_shape=[
             out_shape,
-            jax.ShapeDtypeStruct((tiles, err_rows, _ref.LANES), jnp.int32,
-                                 vma=vma),
+            jax.ShapeDtypeStruct(err_shape, jnp.int32, vma=vma),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(*scalars, _ref.wire_words(x, word_bits).reshape(c, n // _ref.LANES,
-                                                          _ref.LANES))
-    errs = jnp.sum(errs.reshape(tiles, -1)[:, :c], axis=0)
+    )(*scalars, *operands)
+    if resident:
+        errs = errs.reshape(-1)[:c]
+    else:
+        errs = jnp.sum(errs.reshape(tiles, -1)[:, :c], axis=0)
     if aggregate:
         return out.reshape(n), errs
     return _ref.wire_values(out.reshape(c, n), word_bits), errs
@@ -286,6 +360,7 @@ def approx_channel_batch_aggregate_pallas(
     valid_words: int | None = None,
     interpret: bool = True,
     num_active=None,
+    acc=None,
 ):
     """Fused modulate -> channel -> demodulate -> accumulate, one launch.
 
@@ -308,17 +383,21 @@ def approx_channel_batch_aggregate_pallas(
         all N words — callers slice off their padding.
       num_active: optional scalar — rows at or beyond it skip the PHY chain
         and contribute nothing to the sum (padded adaptive buckets).
+      acc: optional ``(N,)`` f32 running aggregate the sum starts from; its
+        buffer is aliased to the output.
 
     Returns:
       ``(agg (N,) float32, bit_errors (C,) int32)`` with
-      ``agg == sum_c weights[c] * x_hat[c]`` accumulated in client order,
-      bit-identical to ``fedsgd_aggregate_batch`` over the batched kernel.
+      ``agg == acc + sum_c weights[c] * x_hat[c]`` accumulated in client
+      order, bit-identical to ``fedsgd_aggregate_batch`` over the batched
+      kernel.
     """
     return _uplink_call(
         x, seeds, noise_powers, large_scale_gains, weights,
         bits_per_symbol=bits_per_symbol, fading=fading, fade_block=fade_block,
         clamp_mask=clamp_mask, block_words=block_words, word_bits=word_bits,
-        valid_words=valid_words, interpret=interpret, num_active=num_active)
+        valid_words=valid_words, interpret=interpret, num_active=num_active,
+        acc=acc)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)

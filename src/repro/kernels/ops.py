@@ -31,6 +31,7 @@ __all__ = [
     "approx_channel_transmit_batch",
     "approx_channel_transmit_batch_aggregate",
     "default_interpret",
+    "padded_words",
     "donation_supported",
 ]
 
@@ -256,10 +257,11 @@ def approx_channel_transmit_batch(x: jax.Array, keys: jax.Array, cfg,
         interpret=default_interpret(),
         num_active=num_active,
     )
+    # Counts as floats: a 568M-float payload has 9.1e9 symbols.
     ones = jnp.ones((c,), jnp.float32)
     stats = transport_lib.TxStats(
-        ones * (n * (wb // k)), ones, errs.astype(jnp.float32),
-        ones * (n * wb), bits_on_air=ones * (n * wb),
+        ones * float(n * (wb // k)), ones, errs.astype(jnp.float32),
+        ones * float(n * wb), bits_on_air=ones * float(n * wb),
     )
     return x_hat.astype(jnp.float32), stats
 
@@ -279,19 +281,26 @@ def _batch_aggregate_impl(
     word_bits: int = 32,
     interpret: bool = True,
     num_active=None,
+    acc=None,
 ):
     """Fused batch + in-kernel weighted aggregation over the client axis.
 
     Pads ``(C, N)`` payloads to a tile multiple and runs the aggregating
     kernel: the per-client demapped payload never materializes in HBM — the
-    only payload-sized output is the f32 accumulator. Bit errors are masked
-    to the first ``N`` words inside the kernel (``valid_words``). Returns
-    ``(agg (N,) float32, bit_errors (C,) int32)``.
+    only payload-sized output is the f32 accumulator, which starts from the
+    ``(N,)`` running aggregate ``acc`` when one is given. Bit errors are
+    masked to the first ``N`` words inside the kernel (``valid_words``).
+    Returns ``(agg (N,) float32, bit_errors (C,) int32)``.
     """
     c, n = x.shape
     pad = (-n) % block_words
     wire = jnp.bfloat16 if word_bits == 16 else jnp.float32
     xp = jnp.pad(x.astype(wire), ((0, 0), (0, pad)))
+    # An aggregate of the padded length stays padded: a round that streams
+    # waves carries it so, and copies nothing each wave.
+    padded = acc is not None and acc.shape[0] == n + pad
+    if acc is not None and not padded:
+        acc = jnp.pad(acc, (0, pad))
     agg, errs = approx_channel_batch_aggregate_pallas(
         xp,
         jnp.asarray(seeds),
@@ -307,8 +316,9 @@ def _batch_aggregate_impl(
         valid_words=n,
         interpret=interpret,
         num_active=num_active,
+        acc=acc,
     )
-    return agg[:n], errs
+    return (agg if padded else agg[:n]), errs
 
 
 _AGG_STATIC = (
@@ -324,15 +334,23 @@ _batch_aggregate_donated = jax.jit(
     _batch_aggregate_impl, static_argnames=_AGG_STATIC, donate_argnums=(0,))
 
 
+def padded_words(n: int, block_words: int = 1024) -> int:
+    """A payload's length in the kernel's tiles: ``n`` rounded up to
+    ``block_words``."""
+    return n + (-n) % block_words
+
+
 def approx_channel_transmit_batch_aggregate(
         x: jax.Array, keys: jax.Array, cfg, snr_db, weights, *,
-        num_active=None, donate: bool = False):
+        num_active=None, donate: bool = False, acc=None):
     """Batched TransportConfig adapter with in-kernel aggregation.
 
     Same contract as ``approx_channel_transmit_batch`` except the per-client
-    demapped rows collapse to ``sum_c weights[c] * x_hat[c]`` inside the
-    kernel (weights are used as given — normalize first). ``donate=True``
-    releases the ``x`` buffer on backends that honour donation.
+    demapped rows collapse to ``acc + sum_c weights[c] * x_hat[c]`` inside
+    the kernel (weights are used as given — normalize first; ``acc``
+    defaults to zero, and one of :func:`padded_words` length comes back
+    at that length). ``donate=True`` releases the ``x`` buffer on
+    backends that honour donation.
 
     Returns ``(agg (N,) float32, TxStats with (C,) fields)``.
     """
@@ -363,10 +381,12 @@ def approx_channel_transmit_batch_aggregate(
         word_bits=wb,
         interpret=default_interpret(),
         num_active=num_active,
+        acc=acc,
     )
+    # Counts as floats: a 568M-float payload has 9.1e9 symbols.
     ones = jnp.ones((c,), jnp.float32)
     stats = transport_lib.TxStats(
-        ones * (n * (wb // k)), ones, errs.astype(jnp.float32),
-        ones * (n * wb), bits_on_air=ones * (n * wb),
+        ones * float(n * (wb // k)), ones, errs.astype(jnp.float32),
+        ones * float(n * wb), bits_on_air=ones * float(n * wb),
     )
     return agg, stats

@@ -16,6 +16,15 @@ Pipeline (paper Sec. IV, per tile of ``block_words`` float32 words):
 
 Returns ``(x_hat, bit_errors)`` where bit_errors counts residual flipped
 bits vs. the transmitted words (post-clamp).
+
+The RNG counter is a payload's global symbol index. Its low 32 bits are the
+hash input; the words above them (``segment``: the index over 2^32, one
+per 2^32 symbols, 268M float32 words at QPSK) go into the seed,
+``seed ^ fmix32(segment * golden)``, and only where the segment is above
+0. So every payload below 2^32 symbols draws exactly the stream it always
+did, and a longer one never repeats its noise. A tile holds a power-of-two
+number of symbols, so no tile straddles a segment; the per-tile seed is a
+scalar, and no per-symbol work is added.
 """
 
 from __future__ import annotations
@@ -99,6 +108,38 @@ def _i32(x: jax.Array) -> jax.Array:
     """Reinterpret uint32 as int32: Mosaic converts and reduces only signed
     integers, and every value routed through here is below 2^31."""
     return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+SEGMENT_BITS = 32  # the hash counter's width: symbols per seed segment 2^32
+
+
+def segment_seed(seed: jax.Array, segment) -> jax.Array:
+    """A tile's seed: the client's seed in segment 0, else folded with the
+    segment (its symbol counter's bits above 32)."""
+    seed = seed.astype(_U32)
+    segment = jnp.asarray(segment, _U32)
+    return jnp.where(segment > 0, seed ^ fmix32(segment * _U32(0x9E3779B9)),
+                     seed)
+
+
+def segments(tiles: int, block_words: int, s_per_word: int,
+             fading: str = "rayleigh", fade_block: int = 64) -> tuple:
+    """``(n_segments, tile_shift)``: how many seed segments ``tiles`` tiles
+    span, and the shift from a tile index to its segment. Raises where a
+    tile or a fading block would straddle a segment boundary."""
+    per_tile = block_words * s_per_word
+    n = -(-(tiles * per_tile) // (1 << SEGMENT_BITS))
+    if n <= 1:
+        return 1, 0
+    if per_tile & (per_tile - 1):
+        raise ValueError(
+            f"a payload past 2^32 symbols needs a power-of-two number of "
+            f"symbols per tile; block_words={block_words} gives {per_tile}")
+    if fading == "block_rayleigh" and fade_block & (fade_block - 1):
+        raise ValueError(
+            f"a payload past 2^32 symbols needs a power-of-two fade_block; "
+            f"got {fade_block}")
+    return n, SEGMENT_BITS - (per_tile.bit_length() - 1)
 
 
 def tile_word_index(shape) -> jax.Array:
@@ -246,17 +287,22 @@ def ref_approx_channel(
     s_per_word = word_bits // bits_per_symbol
     u = wire_words(x, word_bits)
     tiles = u.reshape(-1, block_words // LANES, LANES)
-    base = jnp.arange(tiles.shape[0], dtype=jnp.int32) * (block_words * s_per_word)
+    _, shift = segments(tiles.shape[0], block_words, s_per_word, fading,
+                        fade_block)
+    index = jnp.arange(tiles.shape[0], dtype=jnp.int32)
+    base = index * (block_words * s_per_word)  # wraps mod 2^32
+    seeds = segment_seed(seed, index >> shift if shift else 0)
+    seeds = jnp.broadcast_to(seeds, index.shape)
 
-    def per_tile(tile, b):
+    def per_tile(tile, b, sd):
         return channel_tile(
-            tile, seed.astype(_U32), b,
+            tile, sd, b,
             jnp.float32(noise_power), jnp.float32(large_scale_gain),
             bits_per_symbol=bits_per_symbol, fading=fading, fade_block=fade_block,
             word_bits=word_bits,
         )
 
-    u_hat = jax.vmap(per_tile)(tiles, base).reshape(-1)
+    u_hat = jax.vmap(per_tile)(tiles, base, seeds).reshape(-1)
     u_hat = u_hat & _U32(clamp_mask)
     errs = jnp.sum(bit_flips(u, u_hat)[:valid_words])
     return wire_values(u_hat, word_bits), errs

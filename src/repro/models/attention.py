@@ -1,4 +1,4 @@
-"""GQA attention: training (full / sliding-window / local) and cached decode.
+"""Attention: GQA/MHA and latent (MLA) projections, training and cached decode.
 
 * ``attend_train``: full causal, sliding-window causal, or non-causal
   (whisper encoder / cross attention) over (B, S, H, hd) projections.
@@ -7,6 +7,12 @@
   Sliding-window caches are ring buffers (B, W, KVH, hd) indexed ``pos % W``
   — this is what makes ``long_500k`` (524288-token context) feasible: the
   live cache is O(window), not O(context).
+
+* ``init_mla`` / ``mla_query`` / ``mla_latent`` / ``mla_expand``: latent
+  attention (DeepSeek-V2/V3, arXiv:2412.19437): the keys and values of every
+  head come up from one normalised latent ``c_kv`` per token, and one
+  rotary key part is shared by all heads. A decode cache holds the latent
+  and the rotary key, not the per-head keys and values.
 
 Softmax is computed in f32; logits scaled by 1/sqrt(hd).
 """
@@ -17,6 +23,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from repro.models import layers as L
 
 NEG_INF = -1e30
 
@@ -86,6 +94,7 @@ def attend_train_blockwise(
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     KVH = k.shape[2]
+    dv = v.shape[-1]
     rep = H // KVH
     assert Sq % block_q == 0 and Sk % block_kv == 0, (Sq, Sk, block_q, block_kv)
     nq, nk = Sq // block_q, Sk // block_kv
@@ -101,7 +110,7 @@ def attend_train_blockwise(
         qpos = i * block_q + jnp.arange(block_q)[:, None] + offs
         m0 = jnp.full((B, KVH, rep, block_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KVH, rep, block_q), jnp.float32)
-        a0 = jnp.zeros((B, KVH, rep, block_q, hd), jnp.float32)
+        a0 = jnp.zeros((B, KVH, rep, block_q, dv), jnp.float32)
 
         def kv_step(carry, inp):
             m, l, acc = carry
@@ -133,7 +142,7 @@ def attend_train_blockwise(
 
     ob = jax.vmap(q_block, in_axes=(1, 0), out_axes=1)(
         qb, jnp.arange(nq))  # (B,nq,bq,KVH,rep,hd)
-    return ob.reshape(B, Sq, H, hd).astype(q.dtype)
+    return ob.reshape(B, Sq, H, dv).astype(q.dtype)
 
 
 def _pick_block(seq: int, target: int) -> int:
@@ -146,6 +155,13 @@ def _pick_block(seq: int, target: int) -> int:
 
 def attend(q, k, v, *, causal=True, window=0, impl="naive",
            block_q=512, block_kv=1024):
+    if impl == "per_sequence":
+        # One sequence's (H, S, S) scores at a time, recomputed in the
+        # backward pass: what a long-sequence gradient can hold.
+        one = jax.checkpoint(lambda a: attend_train(
+            a[0][None], a[1][None], a[2][None], causal=causal,
+            window=window)[0])
+        return jax.lax.map(one, (q, k, v))
     if impl == "blockwise":
         bq = _pick_block(q.shape[1], block_q)
         bk = _pick_block(k.shape[1], block_kv)
@@ -200,3 +216,83 @@ def update_cache_ring(k_ring, v_ring, k_new, v_new, pos):
     k_ring = jax.lax.dynamic_update_slice_in_dim(k_ring, k_new.astype(k_ring.dtype), slot, axis=1)
     v_ring = jax.lax.dynamic_update_slice_in_dim(v_ring, v_new.astype(v_ring.dtype), slot, axis=1)
     return k_ring, v_ring
+
+
+# ------------------------------------------------------ latent attention
+
+
+def init_mla(key, cfg, dtype):
+    """MLA projections: the query (straight, or through a normalised
+    ``q_lora_rank`` latent), the joint latent-and-rotary-key down
+    projection, the latent's RMSNorm, its up projection to every head's
+    non-rotary key and value, and the output."""
+    D, H = cfg.d_model, cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    ks = jax.random.split(key, 5)
+    p = {}
+    if cfg.q_lora_rank:
+        p["wq_a"] = L.dense_init(ks[0], (D, cfg.q_lora_rank), dtype=dtype)
+        p["q_norm"] = jnp.zeros((cfg.q_lora_rank,), dtype)
+        p["wq_b"] = L.dense_init(ks[1], (cfg.q_lora_rank, H * (dn + dr)),
+                                 dtype=dtype)
+    else:
+        p["wq"] = L.dense_init(ks[0], (D, H * (dn + dr)), dtype=dtype)
+    p["wkv_a"] = L.dense_init(ks[2], (D, r + dr), dtype=dtype)
+    p["kv_norm"] = jnp.zeros((r,), dtype)
+    p["wkv_b"] = L.dense_init(ks[3], (r, H * (dn + dv)), dtype=dtype)
+    p["wo"] = L.dense_init(ks[4], (H * dv, D), dtype=dtype)
+    return p
+
+
+def rope_rotate_half(x, positions, theta: float):
+    """Rotary embedding over all of ``x``'s last dim, rotate-half pairing
+    (dim ``i`` with ``i + d/2``). x: (..., S, H, d); positions (..., S)."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, d/2)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[..., None, :]
+    xf = x.astype(jnp.float32)
+    rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
+    return (xf * cos + rot * sin).astype(x.dtype)
+
+
+def mla_query(h, p, cfg, positions):
+    """(B, S, D) -> per-head queries (B, S, H, nope + rope), rotary part
+    rotated."""
+    B, S, _ = h.shape
+    dn = cfg.qk_nope_head_dim
+    if "wq" in p:
+        q = jnp.einsum("bsd,dh->bsh", h, p["wq"])
+    else:
+        qa = L.rmsnorm(jnp.einsum("bsd,dr->bsr", h, p["wq_a"]), p["q_norm"],
+                       cfg.rms_eps)
+        q = jnp.einsum("bsr,rh->bsh", qa, p["wq_b"])
+    q = q.reshape(B, S, cfg.n_heads, dn + cfg.qk_rope_head_dim)
+    q_rope = rope_rotate_half(q[..., dn:], positions, cfg.rope_theta)
+    return jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+
+
+def mla_latent(h, p, cfg, positions):
+    """(B, S, D) -> the normalised latent (B, S, r) and the shared rotary
+    key (B, S, rope), rotated: what a decode cache holds."""
+    r = cfg.kv_lora_rank
+    kv_a = jnp.einsum("bsd,dr->bsr", h, p["wkv_a"])
+    c_kv = L.rmsnorm(kv_a[..., :r], p["kv_norm"], cfg.rms_eps)
+    k_rope = rope_rotate_half(kv_a[..., None, r:], positions,
+                              cfg.rope_theta)[..., 0, :]
+    return c_kv, k_rope
+
+
+def mla_expand(c_kv, k_rope, p, cfg):
+    """Latent and rotary key -> per-head keys (B, S, H, nope + rope) and
+    values (B, S, H, v): the latent's up projection, the rotary key shared
+    by every head."""
+    B, S, _ = c_kv.shape
+    H, dn = cfg.n_heads, cfg.qk_nope_head_dim
+    kv = jnp.einsum("bsr,rh->bsh", c_kv, p["wkv_b"]).reshape(
+        B, S, H, dn + cfg.v_head_dim)
+    rope = jnp.broadcast_to(k_rope[:, :, None, :],
+                            (B, S, H, cfg.qk_rope_head_dim))
+    return jnp.concatenate([kv[..., :dn], rope], axis=-1), kv[..., dn:]

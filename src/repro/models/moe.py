@@ -11,6 +11,17 @@ TPU-native dispatch (MaxText/MegaBlocks-style, no (T, E, C) one-hot blowup):
   6. gather back, combine with routing weights (dropped slots contribute 0)
 
 A load-balance auxiliary loss (Switch-style) is returned alongside.
+
+``routed_ffn`` is the DeepSeek-V3 layer (``router_score == "sigmoid"``):
+sigmoid scores over all the router's experts, the top-k of score plus a
+correction bias selected, the selected scores normalised and scaled; the
+layer holds a contiguous share of the experts (expert parallelism) and
+computes only their part of the result. Its dispatch is the same sorted
+capacity layout over the held experts; with no capacity limit
+(``capacity_factor <= 0``) every token goes through every held expert,
+weighted by its gate (0 where the expert was not selected), which drops
+nothing and needs no gather or scatter. It counts the pairs it computed and
+any it dropped.
 """
 
 from __future__ import annotations
@@ -23,13 +34,21 @@ from repro.models import layers as L
 
 def init_moe(key, cfg, dtype):
     D, F, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    Eh = cfg.held_experts
     ks = jax.random.split(key, 5)
     p = {
         "router": L.dense_init(ks[0], (D, E), dtype=jnp.float32),
-        "wi": L.dense_init(ks[1], (E, D, F), in_axis=-2, dtype=dtype),
-        "wg": L.dense_init(ks[2], (E, D, F), in_axis=-2, dtype=dtype),
-        "wo": L.dense_init(ks[3], (E, F, D), in_axis=-2, dtype=dtype),
+        "wi": L.dense_init(ks[1], (Eh, D, F), in_axis=-2, dtype=dtype),
+        "wg": L.dense_init(ks[2], (Eh, D, F), in_axis=-2, dtype=dtype),
+        "wo": L.dense_init(ks[3], (Eh, F, D), in_axis=-2, dtype=dtype),
     }
+    if cfg.router_score == "sigmoid":
+        # The correction bias steers selection only: a buffer, which the
+        # forward pass holds out of the gradient. Its key is the init key's
+        # sixth draw, no round or client lane.
+        p["router_bias"] = cfg.router_bias_std * jax.random.normal(
+            jax.random.fold_in(key, 5),  # lint: ignore[keylane]
+            (E,), jnp.float32)
     if cfg.n_shared_experts:
         Fs = cfg.moe_d_ff * cfg.n_shared_experts
         kk = jax.random.split(ks[4], 3)
@@ -108,6 +127,103 @@ def moe_ffn(x: jax.Array, p, cfg):
         s = p["shared"]
         out = out + L.swiglu(x2, s["wi"], s["wg"], s["wo"])
     return out.reshape(orig_shape), aux
+
+
+def route_sigmoid(x2, router, bias, cfg):
+    """``(T, D)`` -> selected experts ``(T, K)`` int32 and their weights
+    ``(T, K)`` f32: top-k of ``sigmoid(x W_r) + bias``, weighted by the
+    selected scores (normalised over the k when ``norm_topk_prob``) times
+    ``routed_scale``. The bias changes which experts are chosen, never
+    their weights."""
+    scores = jax.nn.sigmoid(x2.astype(jnp.float32) @ router.astype(jnp.float32))
+    _, sel = jax.lax.top_k(scores + bias, cfg.top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return sel, w * cfg.routed_scale
+
+
+def held_capacity(n_tokens: int, cfg) -> int:
+    """Slots per held expert: ``capacity_factor`` times the mean load
+    ``T * k / n_experts``, never above ``T`` (a token picks an expert once);
+    ``T`` when ``capacity_factor <= 0`` (no limit)."""
+    if cfg.capacity_factor <= 0:
+        return n_tokens
+    c = -(-int(n_tokens * cfg.top_k * cfg.capacity_factor) // cfg.n_experts)
+    return min(max(c, 8), n_tokens)
+
+
+def _held_dense(x2, sel, w, p, cfg):
+    """Every token through every held expert, weighted by its gate (0 where
+    the expert was not selected): ``(T, D)``. The gates scale each expert's
+    hidden activations, so one contraction over experts and width sums
+    them."""
+    gate = jnp.sum(jax.nn.one_hot(sel - cfg.expert_offset, cfg.held_experts,
+                                  dtype=jnp.float32) * w[..., None], axis=1)
+    hi = jnp.einsum("td,edf->etf", x2, p["wi"])
+    hg = jnp.einsum("td,edf->etf", x2, p["wg"])
+    a = jax.nn.silu(hg) * hi * gate.T[:, :, None].astype(hi.dtype)
+    return jnp.einsum("etf,efd->td", a, p["wo"])
+
+
+def routed_ffn(x: jax.Array, p, bias, cfg):
+    """The sigmoid-routed expert share: ``(..., D)`` -> ``(out, counters)``.
+
+    ``out`` is the held experts' part, ``sum_{i in sel, held} g_i
+    SwiGLU_i(x)``, plus the shared experts. Pairs (token, selected expert)
+    whose expert is held are sorted by expert into ``(E_held, C)`` slots,
+    or, when a slot row holds every token (``C == T``), every token goes
+    through every held expert with its gate (:func:`_held_dense`).
+    ``counters`` holds ``moe_local_tokens`` (held pairs) and
+    ``moe_dropped_tokens`` (held pairs beyond a slot row: 0 when dropless).
+    """
+    shape = x.shape
+    D = shape[-1]
+    x2 = x.reshape(-1, D)
+    T = x2.shape[0]
+    K, Eh = cfg.top_k, cfg.held_experts
+    C = held_capacity(T, cfg)
+    with jax.named_scope("lm_router"):
+        sel, w = route_sigmoid(x2, p["router"], bias, cfg)
+        pair_e = sel.reshape(-1) - cfg.expert_offset
+        held = (pair_e >= 0) & (pair_e < Eh)
+        counters = {"moe_local_tokens": jnp.sum(held.astype(jnp.int32)),
+                    "moe_dropped_tokens": jnp.int32(0)}
+    if C == T:
+        with jax.named_scope("lm_experts"):
+            out = _held_dense(x2, sel, w, p, cfg)
+    else:
+        with jax.named_scope("lm_router"):
+            # Held pairs first, by expert; the rest sort past them.
+            order = jnp.argsort(jnp.where(held, pair_e, Eh), stable=True)
+            se = jnp.where(held, pair_e, Eh)[order]
+            ar = jnp.arange(T * K, dtype=jnp.int32)
+            change = jnp.concatenate([jnp.ones((1,), bool),
+                                      se[1:] != se[:-1]])
+            pos = ar - jax.lax.associative_scan(jnp.maximum,
+                                                jnp.where(change, ar, 0))
+            keep = (se < Eh) & (pos < C)
+            trash = Eh * C
+            slot = jnp.where(keep, se * C + pos, trash)
+            tok = jnp.zeros((trash + 1,), jnp.int32).at[slot].set(
+                (order // K).astype(jnp.int32))[:trash]
+            wt = jnp.zeros((trash + 1,), jnp.float32).at[slot].set(
+                w.reshape(-1)[order])[:trash]
+            counters["moe_dropped_tokens"] = jnp.sum(
+                (held[order] & ~keep).astype(jnp.int32))
+        with jax.named_scope("lm_experts"):
+            h = x2[tok].reshape(Eh, C, D)
+            hi = jnp.einsum("ecd,edf->ecf", h, p["wi"])
+            hg = jnp.einsum("ecd,edf->ecf", h, p["wg"])
+            y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(hg) * hi, p["wo"])
+            # Empty slots carry weight 0 and add nothing to token 0.
+            out = jnp.zeros((T, D), y.dtype).at[tok].add(
+                y.reshape(trash, D) * wt[:, None].astype(y.dtype))
+    if cfg.n_shared_experts:
+        with jax.named_scope("lm_ffn"):
+            s = p["shared"]
+            out = out + L.swiglu(x2, s["wi"], s["wg"], s["wo"])
+    return out.reshape(shape), counters
 
 
 # ------------------------------------------------------------------------

@@ -3,8 +3,10 @@
 One implementation covers:
 * ``dense``  — llama-style: RMSNorm, RoPE (optionally partial), GQA,
   SwiGLU; optional QKV bias (qwen2/chatglm), optional sliding window.
-* ``moe``    — same attention; FFN replaced by top-k expert routing
-  (``repro.models.moe``), optional leading dense layers + shared experts.
+* ``moe``    — same attention, or latent attention (MLA) when
+  ``kv_lora_rank`` is set; FFN replaced by top-k expert routing
+  (``repro.models.moe``: softmax with capacity, or the sigmoid-routed
+  expert share), optional leading dense layers + shared experts.
 * ``vlm``    — dense decoder consuming a projected patch-embedding prefix
   (vision encoder is a stub per the brief).
 * ``hybrid`` — Griffin/RecurrentGemma: RG-LRU recurrent blocks with a local
@@ -18,6 +20,7 @@ API (used by launchers, smoke tests and the dry-run):
     init_params(key, cfg)                       -> params
     forward(params, batch, cfg)                 -> (logits, aux_loss)
     loss_fn(params, batch, cfg)                 -> scalar loss
+    lm_loss(params, tokens, labels, cfg)        -> (loss, counters)
     init_cache(cfg, batch, cache_len)           -> cache
     decode_step(params, cache, tokens, pos, cfg)-> (logits, cache)
 """
@@ -41,6 +44,8 @@ Params = Any
 
 
 def _init_attn(key, cfg, dtype):
+    if cfg.is_mla:
+        return A.init_mla(key, cfg, dtype)
     D = cfg.d_model
     hd = cfg.resolved_head_dim
     H, KVH = cfg.n_heads, cfg.n_kv_heads
@@ -182,25 +187,42 @@ def _project_qkv(x, p, cfg, positions):
 
 
 def _attn_block(x, p, cfg, positions, window):
-    h = L.rmsnorm(x, p["ln1"])
-    q, k, v = _project_qkv(h, p["attn"], cfg, positions)
-    o = A.attend(q, k, v, causal=True, window=window, impl=cfg.attn_impl)
-    o = jnp.einsum("bsh,he->bse", o.reshape(o.shape[0], o.shape[1], -1), p["attn"]["wo"])
-    return x + o.astype(x.dtype)
+    with jax.named_scope("lm_attn"):
+        h = L.rmsnorm(x, p["ln1"], cfg.rms_eps)
+        if cfg.is_mla:
+            q = A.mla_query(h, p["attn"], cfg, positions)
+            k, v = A.mla_expand(*A.mla_latent(h, p["attn"], cfg, positions),
+                                p["attn"], cfg)
+        else:
+            q, k, v = _project_qkv(h, p["attn"], cfg, positions)
+        o = A.attend(q, k, v, causal=True, window=window, impl=cfg.attn_impl)
+        o = jnp.einsum("bsh,he->bse", o.reshape(o.shape[0], o.shape[1], -1), p["attn"]["wo"])
+        return x + o.astype(x.dtype)
 
 
 def _mlp_block(x, p, cfg):
-    h = L.rmsnorm(x, p["ln2"])
-    return x + L.swiglu(h, p["mlp"]["wi"], p["mlp"]["wg"], p["mlp"]["wo"])
+    with jax.named_scope("lm_ffn"):
+        h = L.rmsnorm(x, p["ln2"], cfg.rms_eps)
+        return x + L.swiglu(h, p["mlp"]["wi"], p["mlp"]["wg"], p["mlp"]["wo"])
+
+
+NO_COUNTERS = {"moe_local_tokens": 0, "moe_dropped_tokens": 0}
 
 
 def _moe_block(x, p, cfg):
-    h = L.rmsnorm(x, p["ln2"])
-    if cfg.moe_impl == "expert_parallel":
+    """Returns ``(x + moe(x), aux_loss, counters)``; the sigmoid-routed
+    share has no aux loss, the softmax layers count nothing."""
+    h = L.rmsnorm(x, p["ln2"], cfg.rms_eps)
+    counters = {k: jnp.int32(v) for k, v in NO_COUNTERS.items()}
+    aux = jnp.float32(0.0)
+    if cfg.router_score == "sigmoid":
+        bias = jax.lax.stop_gradient(p["moe"]["router_bias"])
+        out, counters = MOE.routed_ffn(h, p["moe"], bias, cfg)
+    elif cfg.moe_impl == "expert_parallel":
         out, aux = MOE.moe_ffn_shardmap(h, p["moe"], cfg)
     else:
         out, aux = MOE.moe_ffn(h, p["moe"], cfg)
-    return x + out, aux
+    return x + out, aux, counters
 
 
 def _rglru_scan(xg, rec, h0=None):
@@ -262,6 +284,12 @@ def _embed_tokens(params, tokens, cfg):
 
 def forward(params: Params, batch: dict, cfg) -> tuple[jax.Array, jax.Array]:
     """Training/prefill forward. Returns (logits f32 (B,S,V), aux_loss)."""
+    logits, aux, _ = _forward(params, batch, cfg)
+    return logits, aux
+
+
+def _forward(params: Params, batch: dict, cfg):
+    """:func:`forward` with the MoE layers' counters, summed over layers."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     x = _embed_tokens(params, tokens, cfg)
@@ -275,6 +303,7 @@ def forward(params: Params, batch: dict, cfg) -> tuple[jax.Array, jax.Array]:
     window = cfg.sliding_window
 
     aux_total = jnp.float32(0.0)
+    counters = {k: jnp.int32(v) for k, v in NO_COUNTERS.items()}
     if cfg.family in ("dense", "vlm"):
         def body(carry, pl):
             h = _attn_block(carry, pl, cfg, positions, window)
@@ -292,12 +321,14 @@ def forward(params: Params, batch: dict, cfg) -> tuple[jax.Array, jax.Array]:
             x, _ = jax.lax.scan(jax.checkpoint(dbody), x, params["dense_layers"], unroll=cfg.scan_unroll)
 
         def mbody(carry, pl):
-            h, aux = carry
+            h, aux, cnt = carry
             h = _attn_block(h, pl, cfg, positions, window)
-            h, a = _moe_block(h, pl, cfg)
-            return (h, aux + a), None
+            h, a, c = _moe_block(h, pl, cfg)
+            return (h, aux + a, jax.tree_util.tree_map(jnp.add, cnt, c)), None
 
-        (x, aux_total), _ = jax.lax.scan(jax.checkpoint(mbody), (x, aux_total), params["layers"], unroll=cfg.scan_unroll)
+        (x, aux_total, counters), _ = jax.lax.scan(
+            jax.checkpoint(mbody), (x, aux_total, counters), params["layers"],
+            unroll=cfg.scan_unroll)
     elif cfg.family == "hybrid":
         p = cfg.attn_period
 
@@ -316,12 +347,13 @@ def forward(params: Params, batch: dict, cfg) -> tuple[jax.Array, jax.Array]:
     else:
         raise ValueError(cfg.family)
 
-    x = L.rmsnorm(x, params["final_norm"])
-    if prefix:
-        x = x[:, prefix:]
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
-    return logits, aux_total
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.rms_eps)
+        if prefix:
+            x = x[:, prefix:]
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    return logits, aux_total, counters
 
 
 def _gold_logit(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -341,6 +373,18 @@ def loss_fn(params: Params, batch: dict, cfg) -> jax.Array:
     return nll + cfg.aux_loss_coef * aux
 
 
+def lm_loss(params: Params, tokens, labels, cfg):
+    """Mean next-token cross-entropy of ``(B, S)`` tokens against their
+    labels, and the MoE counters: ``(loss, counters)``. The softmax
+    layers' aux loss is added as in :func:`loss_fn`."""
+    logits, aux, counters = _forward(params, {"tokens": tokens}, cfg)
+    with jax.named_scope("lm_head"):
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = jnp.mean(lse - gold)
+    return nll + cfg.aux_loss_coef * aux, counters
+
+
 # ----------------------------------------------------------------- decode
 
 
@@ -352,13 +396,16 @@ def init_cache(cfg, batch_size: int, cache_len: int, dtype=None) -> dict:
     nL = cfg.n_layers
     if cfg.family in ("dense", "vlm", "moe"):
         nd = cfg.first_dense_layers if cfg.family == "moe" else 0
+        # MLA caches the latent ("k") and the rotary key ("v") per token.
+        k_tail = (cfg.kv_lora_rank,) if cfg.is_mla else (KVH, hd)
+        v_tail = (cfg.qk_rope_head_dim,) if cfg.is_mla else (KVH, hd)
         cache = {
-            "k": jnp.zeros((nL - nd, batch_size, cache_len, KVH, hd), dtype),
-            "v": jnp.zeros((nL - nd, batch_size, cache_len, KVH, hd), dtype),
+            "k": jnp.zeros((nL - nd, batch_size, cache_len) + k_tail, dtype),
+            "v": jnp.zeros((nL - nd, batch_size, cache_len) + v_tail, dtype),
         }
         if nd:
-            cache["dk"] = jnp.zeros((nd, batch_size, cache_len, KVH, hd), dtype)
-            cache["dv"] = jnp.zeros((nd, batch_size, cache_len, KVH, hd), dtype)
+            cache["dk"] = jnp.zeros((nd, batch_size, cache_len) + k_tail, dtype)
+            cache["dv"] = jnp.zeros((nd, batch_size, cache_len) + v_tail, dtype)
         return cache
     if cfg.family == "hybrid":
         W = cfg.lru_width or cfg.d_model
@@ -385,7 +432,18 @@ def _decode_attn(x, p, cfg, kc, vc, pos, ring: bool):
     """One-token attention for a single layer. x: (B,1,D)."""
     B = x.shape[0]
     hd = cfg.resolved_head_dim
-    h = L.rmsnorm(x, p["ln1"])
+    h = L.rmsnorm(x, p["ln1"], cfg.rms_eps)
+    if cfg.is_mla:
+        posb = jnp.full((1, 1), pos, jnp.int32)
+        q = A.mla_query(h, p["attn"], cfg, posb)
+        c_kv, k_rope = A.mla_latent(h, p["attn"], cfg, posb)
+        update = A.update_cache_ring if ring else A.update_cache_full
+        kc, vc = update(kc, vc, c_kv, k_rope, pos)
+        k, v = A.mla_expand(kc, vc, p["attn"], cfg)
+        attend = A.decode_attend_ring if ring else A.decode_attend_full
+        o = attend(q, k, v, pos)
+        o = jnp.einsum("bth,he->bte", o.reshape(B, 1, -1), p["attn"]["wo"])
+        return x + o.astype(x.dtype), kc, vc
     q = jnp.einsum("btd,dh->bth", h, p["attn"]["wq"])
     k = jnp.einsum("btd,dh->bth", h, p["attn"]["wk"])
     v = jnp.einsum("btd,dh->bth", h, p["attn"]["wv"])
@@ -435,7 +493,7 @@ def decode_step(params, cache, tokens, pos, cfg, *, ring: bool = False):
             pl, kc, vc = inp
             h, kc, vc = _decode_attn(h, pl, cfg, kc, vc, pos, ring)
             if cfg.family == "moe":
-                h, _ = _moe_block(h, pl, cfg)
+                h, _, _ = _moe_block(h, pl, cfg)
             else:
                 h = _mlp_block(h, pl, cfg)
             return h, (kc, vc)
@@ -468,7 +526,7 @@ def decode_step(params, cache, tokens, pos, cfg, *, ring: bool = False):
     else:
         raise ValueError(cfg.family)
 
-    x = L.rmsnorm(x, params["final_norm"])
+    x = L.rmsnorm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("btd,dv->btv", x, head).astype(jnp.float32)
     return logits, cache

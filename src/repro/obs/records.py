@@ -118,6 +118,7 @@ class RoundRecord:
     uplink_bits: float | None = None  # cohort payload bits offered
     uplink_bit_errors: float | None = None  # cohort residual bit errors
     uplink_ber: float | None = None  # cohort end-to-end payload BER
+    counters: dict | None = None  # the payload model's counters this round
     uplink_mean_tx: float | None = None  # mean PHY transmissions/client
     uplink_bits_on_air: float | None = None  # cohort bits actually on air
     # -- schema v2: constant-size per-client distribution sketches

@@ -117,3 +117,27 @@ def test_sharded_uplink_compiles_for_four_v5e(data_mesh, monkeypatch,
 
     compiled = call.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_aggregate_kernel_past_two_to_the_32_symbols_compiles_for_v5e(
+        one_chip):
+    """One client of a 568.5M-float payload (9.1 G QPSK symbols, three seed
+    segments) into a running aggregate aliased to the output: one wave of
+    a streamed cohort."""
+    d = 568_486_912
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    call = jax.jit(lambda x, s, n, g, w, acc: (
+        kernels.approx_channel_batch_aggregate_pallas(
+            x, s, n, g, w, acc=acc, fading="rayleigh",
+            clamp_mask=float_codec.exponent_clamp_mask(2.0),
+            interpret=False)), donate_argnums=5)
+    compiled = call.lower(
+        spec((1, d), jnp.float32), spec((1,), jnp.uint32),
+        spec((1,), jnp.float32), spec((1,), jnp.float32),
+        spec((1,), jnp.float32), spec((d,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # The running aggregate is the output's buffer.
+    assert compiled.memory_analysis().alias_size_in_bytes >= d * 4
