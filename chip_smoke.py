@@ -3,10 +3,15 @@
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # the cross-chip path only, on four chips
 
-One chip: both Pallas uplink kernels (batch and fused-aggregate, masked and
-unmasked) against the ``kernels/ref.py`` oracle for QPSK / 16-QAM / 256-QAM
-on the f32 and bf16 wires, at the paper's cohort (100 clients) and CNN width
-(21,840 parameters, padded to 22 tiles of 1,024 words); then 5 FedSGD rounds
+One chip: the sine and cosine of every Box-Muller angle (all 2^24 values of
+``2*pi*uniform01(h)``), by ``jnp.sin`` / ``jnp.cos`` in XLA and inside a
+Pallas kernel and by the kernel's fast ``sincos_2pi``, against float64,
+within the bounds the kernel's exact-recompute margin assumes; both Pallas
+uplink kernels (batch and fused-aggregate, masked and unmasked) against the
+``kernels/ref.py`` oracle for QPSK / 16-QAM / 256-QAM on the f32 and bf16
+wires, at the paper's cohort (100 clients) and CNN width (21,840
+parameters, padded to 22 tiles of 1,024 words), with the share of tiles
+that took the kernel's exact path; then 5 FedSGD rounds
 of ``repro.fl.loop.run_fl`` with the fused round, compared with the layered
 round from the same seed, on the ``vehicular`` scenario and on the paper's
 static single-mode uplink.
@@ -128,6 +133,59 @@ def bf16_subnormal_probe() -> dict:
             for name, fn in stages.items()}
 
 
+def _angle_tables(interpret: bool, rows: int = 512):
+    """``(sin, cos, fast_sin, fast_cos)`` of all 2^24 Box-Muller angles,
+    computed inside a Pallas kernel: ``jnp.sin`` / ``jnp.cos`` and the
+    uplink kernel's ``sincos_2pi``."""
+    from jax.experimental import pallas as pl
+
+    n_rows = (1 << 24) // kref.LANES
+
+    def body(sin_ref, cos_ref, fsin_ref, fcos_ref):
+        i = pl.program_id(0) * (rows * kref.LANES) + kref.tile_word_index(
+            sin_ref.shape)
+        ang = jnp.float32(kref._TWO_PI) * kref.uniform01(
+            kref._u32(i) << jnp.uint32(8))
+        sin_ref[...] = jnp.sin(ang)
+        cos_ref[...] = jnp.cos(ang)
+        fsin_ref[...], fcos_ref[...] = kernels.sincos_2pi(ang)
+
+    spec = pl.BlockSpec((rows, kref.LANES), lambda i: (i, 0))
+    shape = jax.ShapeDtypeStruct((n_rows, kref.LANES), jnp.float32)
+    return pl.pallas_call(body, grid=(n_rows // rows,),
+                          out_specs=[spec] * 4, out_shape=[shape] * 4,
+                          interpret=interpret)()
+
+
+def sincos_vs_float64() -> bool:
+    """The platform's sine and cosine, in XLA and inside a kernel, and the
+    kernel's fast pair, over every angle ``2*pi*uniform01(h)`` (2^24 of
+    them), against float64: the largest absolute errors, in units of
+    2^-24, against ``PLATFORM_SINCOS_ERR`` and ``SINCOS_ERR``."""
+    unit = 2.0**-24
+    i = jnp.arange(1 << 24, dtype=jnp.int32)
+    ang = jax.jit(lambda i: jnp.float32(kref._TWO_PI) * kref.uniform01(
+        kref._u32(i) << jnp.uint32(8)))(i)
+    xla = jax.jit(lambda a: (jnp.sin(a), jnp.cos(a)))(ang)
+    tables = _angle_tables(default_interpret())
+    a64 = np.asarray(ang, np.float64)
+    true = (np.sin(a64), np.cos(a64))
+    limit = {"xla": kernels.PLATFORM_SINCOS_ERR,
+             "kernel": kernels.PLATFORM_SINCOS_ERR,
+             "fast": kernels.SINCOS_ERR}
+    got = {"xla": xla, "kernel": tables[:2], "fast": tables[2:]}
+    ok = True
+    for name, (sin, cos) in got.items():
+        err = [float(np.max(np.abs(np.asarray(v, np.float64).reshape(-1) - t)))
+               for v, t in zip((sin, cos), true)]
+        within = max(err) <= limit[name]
+        ok &= within
+        log(f"sincos_vs_float64 {name:6s}: max_abs_error sin="
+            f"{err[0] / unit:.4g} cos={err[1] / unit:.4g} x 2^-24, bound "
+            f"{limit[name] / unit:g} x 2^-24 -> {'ok' if within else 'FAIL'}")
+    return ok
+
+
 def kernels_vs_ref(c: int = CLIENTS, n: int = PAYLOAD) -> bool:
     """Both uplink kernels, masked and unmasked, against the oracle.
 
@@ -173,8 +231,8 @@ def kernels_vs_ref(c: int = CLIENTS, n: int = PAYLOAD) -> bool:
                         **extra))
                 (rows, errs), t_first = _timed(batch, x, seeds, noise, gains)
                 _, t_steady = _timed(batch, x, seeds, noise, gains)
-                (agg, agg_errs), _ = _timed(fused, x, seeds, noise, gains,
-                                            weights)
+                (agg, agg_errs, exact), _ = _timed(fused, x, seeds, noise,
+                                                   gains, weights)
 
                 kb, rb = _bits(rows), _bits(ref_rows)
                 n_diff = int((kb[:na] != rb[:na]).sum())
@@ -204,6 +262,8 @@ def kernels_vs_ref(c: int = CLIENTS, n: int = PAYLOAD) -> bool:
                 fused_identical = bool(
                     (_bits(agg) == _bits(layered)).all()
                     and (agg_errs[:na] == errs[:na]).all())
+                exact_share = float(np.asarray(exact)[:na].sum()) / (
+                    na * (x.shape[1] // BLOCK_WORDS))
                 finite = bool(np.isfinite(np.asarray(
                     rows[:na], np.float32)).all())
                 # Identical rows must give identical counters; otherwise the
@@ -232,6 +292,7 @@ def kernels_vs_ref(c: int = CLIENTS, n: int = PAYLOAD) -> bool:
                     f"rx_zero_exponent_words={n_zero_exp} "
                     f"rx_subnormal_words={n_subnormal} "
                     f"fused==layered_scan={fused_identical} "
+                    f"exact_tile_share={exact_share:.4g} "
                     f"masked_tail_zero={tail_zero} "
                     f"batch_first_s={t_first:.3f} batch_steady_s={t_steady:.4f}"
                     f" -> {'ok' if case_ok else 'FAIL'}")
@@ -451,7 +512,8 @@ def main() -> int:
         f"jax={jax.__version__}")
 
     phases = ([("mesh_path", mesh_path)] if args.chips == 4 else
-              [("kernels_vs_ref", kernels_vs_ref), ("fl_rounds", fl_rounds)])
+              [("sincos_vs_float64", sincos_vs_float64),
+               ("kernels_vs_ref", kernels_vs_ref), ("fl_rounds", fl_rounds)])
     ok = True
     for name, phase in phases:
         t = time.perf_counter()

@@ -207,6 +207,10 @@ class TxStats:
     for :func:`transmit_batch_adaptive` — indices into the config table the
     caller dispatched over, so ``latency.round_airtime_adaptive`` can price
     each client's airtime under its own mode.
+
+    ``exact_tiles`` is set by the fused uplink-and-aggregate kernel alone:
+    each client's count of tiles that its exact recompute path redid
+    (``kernels/approx_channel.py``); ``None`` elsewhere.
     """
 
     data_symbols: jax.Array  # symbols of payload actually sent (incl. retx)
@@ -215,6 +219,7 @@ class TxStats:
     n_bits: jax.Array
     mode_idx: Any = None  # (num_clients,) int32 for adaptive batches
     bits_on_air: Any = None  # total bits on air (payload + header + parity)
+    exact_tiles: Any = None  # (num_clients,) int32, fused kernel launches
 
     @property
     def ber(self) -> jax.Array:

@@ -868,6 +868,10 @@ class RoundEngine:
                     agg, stats = \
                         transport_lib.transmit_pytree_batch_aggregate(
                             payload, key, tcfg, uniform_w, donate=True)
+                if stats.exact_tiles is not None:
+                    # The kernel's tiles redone on its exact path.
+                    counters = dict(counters, uplink_exact_tiles=jnp.sum(
+                        stats.exact_tiles))
                 params, aux = algo.apply(params, aux, agg)
                 return params, aux, stats, dstats, counters
 

@@ -290,7 +290,9 @@ def _batch_aggregate_impl(
     only payload-sized output is the f32 accumulator, which starts from the
     ``(N,)`` running aggregate ``acc`` when one is given. Bit errors are
     masked to the first ``N`` words inside the kernel (``valid_words``).
-    Returns ``(agg (N,) float32, bit_errors (C,) int32)``.
+    Returns ``(agg (N,) float32, bit_errors (C,) int32, exact_tiles (C,)
+    int32)``, the last the kernel's count of each client's tiles that took
+    its exact path.
     """
     c, n = x.shape
     pad = (-n) % block_words
@@ -301,7 +303,7 @@ def _batch_aggregate_impl(
     padded = acc is not None and acc.shape[0] == n + pad
     if acc is not None and not padded:
         acc = jnp.pad(acc, (0, pad))
-    agg, errs = approx_channel_batch_aggregate_pallas(
+    agg, errs, exact = approx_channel_batch_aggregate_pallas(
         xp,
         jnp.asarray(seeds),
         jnp.asarray(noise_powers, jnp.float32),
@@ -318,7 +320,7 @@ def _batch_aggregate_impl(
         num_active=num_active,
         acc=acc,
     )
-    return (agg if padded else agg[:n]), errs
+    return (agg if padded else agg[:n]), errs, exact
 
 
 _AGG_STATIC = (
@@ -352,7 +354,8 @@ def approx_channel_transmit_batch_aggregate(
     at that length). ``donate=True`` releases the ``x`` buffer on
     backends that honour donation.
 
-    Returns ``(agg (N,) float32, TxStats with (C,) fields)``.
+    Returns ``(agg (N,) float32, TxStats with (C,) fields)``; the stats
+    carry the kernel's ``exact_tiles``.
     """
     from repro.core import channel as channel_lib
     from repro.core import transport as transport_lib
@@ -368,7 +371,7 @@ def approx_channel_transmit_batch_aggregate(
     gains = jnp.full((c,), ch.large_scale_gain, jnp.float32)
     fn = (_batch_aggregate_donated if donate and donation_supported()
           else approx_channel_batch_aggregate)
-    agg, errs = fn(
+    agg, errs, exact = fn(
         x,
         seeds,
         npow,
@@ -388,5 +391,6 @@ def approx_channel_transmit_batch_aggregate(
     stats = transport_lib.TxStats(
         ones * float(n * (wb // k)), ones, errs.astype(jnp.float32),
         ones * float(n * wb), bits_on_air=ones * float(n * wb),
+        exact_tiles=exact,
     )
     return agg, stats

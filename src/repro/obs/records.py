@@ -118,7 +118,9 @@ class RoundRecord:
     uplink_bits: float | None = None  # cohort payload bits offered
     uplink_bit_errors: float | None = None  # cohort residual bit errors
     uplink_ber: float | None = None  # cohort end-to-end payload BER
-    counters: dict | None = None  # the payload model's counters this round
+    # the payload model's counters this round, and the fused uplink
+    # kernel's ``uplink_exact_tiles``
+    counters: dict | None = None
     uplink_mean_tx: float | None = None  # mean PHY transmissions/client
     uplink_bits_on_air: float | None = None  # cohort bits actually on air
     # -- schema v2: constant-size per-client distribution sketches

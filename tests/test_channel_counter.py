@@ -84,7 +84,7 @@ def test_the_kernel_picks_each_tiles_segment_seed_as_the_oracle(monkeypatch):
     gain = jnp.full((c,), GAIN)
     hat, errs = approx_channel_batch_pallas(
         x, seeds, noise, gain, clamp_mask=0xFFFFFFFF, interpret=True)
-    agg, agg_errs = approx_channel_batch_aggregate_pallas(
+    agg, agg_errs, _ = approx_channel_batch_aggregate_pallas(
         x, seeds, noise, gain, jnp.full((c,), 0.5), clamp_mask=0xFFFFFFFF,
         interpret=True)
     total = jnp.zeros((n,), jnp.float32)
@@ -120,7 +120,7 @@ def test_the_resident_counter_block_counts_as_the_per_tile_blocks(
     acc = jax.random.normal(jax.random.PRNGKey(3), (n,))
     hat, errs = approx_channel_batch_pallas(
         x, seeds, noise, gain, clamp_mask=0xFFFFFFFF, interpret=True)
-    agg, agg_errs = approx_channel_batch_aggregate_pallas(
+    agg, agg_errs, _ = approx_channel_batch_aggregate_pallas(
         x, seeds, noise, gain, weights, clamp_mask=0xFFFFFFFF,
         interpret=True, acc=acc)
     total = acc

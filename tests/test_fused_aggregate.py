@@ -296,6 +296,25 @@ def test_engine_sync_fused_golden(mcfg, world):
                             fused_aggregate=True, **kw))
 
 
+def test_engine_fused_round_counts_the_kernels_exact_tiles(mcfg, world):
+    """Each fused round's counters hold the uplink kernel's count of
+    (client, tile) pairs that took its exact path, read with the
+    airtime; the layered round has no such kernel."""
+    from repro.fl.loop import run_fl
+
+    cx, cy, ti, tl = world
+    tc = _cfg(mode="approx", use_kernel=True,
+              channel=CH.ChannelConfig(snr_db=0.0))
+    kw = dict(n_rounds=2, batch_per_round=8, eval_every=1, seed=3)
+    fused = run_fl(mcfg, tc, cx, cy, ti, tl, fused_aggregate=True, **kw)
+    layered = run_fl(mcfg, tc, cx, cy, ti, tl, **kw)
+    steps = cx.shape[0] * O.padded_words(21_840) // 1024
+    counts = [r.counters["uplink_exact_tiles"] for r in fused.records]
+    assert all(0 < n <= steps for n in counts)
+    assert not any((r.counters or {}).get("uplink_exact_tiles")
+                   for r in layered.records)
+
+
 @pytest.mark.slow
 def test_engine_scenario_fused_golden(mcfg, world):
     """Scenario-driven bucketed rounds (dropout included — dropped clients

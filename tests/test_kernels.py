@@ -4,6 +4,8 @@ Exactness (not allclose): kernel and ref share the counter-RNG, so outputs
 must match bit-for-bit across every modulation / fading / shape swept here.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,3 +145,164 @@ def test_kernel_bf16_wire(k):
     assert mism <= 0.005
     assert int(ref_err) == int(ker_err) or mism > 0
     assert np.isfinite(k32).all() and (np.abs(k32) < 2.0).all()
+
+
+# ------------------------------------------- fast path and exact recompute
+
+K = importlib.import_module("repro.kernels.approx_channel")
+
+
+def _cohort(c, n, snr_db, seed=21):
+    x = jax.random.uniform(jax.random.PRNGKey(seed), (c, n),
+                           minval=-1.9, maxval=1.9)
+    seeds = jnp.arange(c, dtype=jnp.uint32) * jnp.uint32(0x9E3779B1) + 5
+    noise = jnp.full((c,), G0 / 10 ** (snr_db / 10), jnp.float32)
+    gains = jnp.full((c,), G0, jnp.float32)
+    weights = jnp.linspace(0.5, 1.5, c, dtype=jnp.float32)
+    return x, seeds, noise, gains, weights
+
+
+def _ref_rows(x, seeds, noise, gains, **params):
+    return [R.ref_approx_channel(x[i], seeds[i], noise[i], gains[i], **params)
+            for i in range(x.shape[0])]
+
+
+def _assert_is_the_scan(agg, refs, weights):
+    """The fused kernel's sum is the client-order scan of the oracle's
+    rows (``transport._scan_weighted_sum``, the layered round's sum)."""
+    from repro.core import transport as T
+
+    rows = jnp.stack([want.astype(jnp.float32) for want, _ in refs])
+    np.testing.assert_array_equal(
+        np.asarray(agg).view(np.uint32),
+        np.asarray(T._scan_weighted_sum(rows, weights)).view(np.uint32))
+
+
+@pytest.mark.parametrize("kernel", ["batch", "fused"])
+@pytest.mark.parametrize("word_bits", [32, 16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("fading", ["rayleigh", "block_rayleigh", "awgn"])
+@pytest.mark.parametrize("k", [2, 4, 8], ids=["qpsk", "16qam", "256qam"])
+def test_fast_path_kernels_equal_the_oracle_bit_for_bit(k, fading, word_bits,
+                                                        kernel):
+    """Both kernels, fast path plus exact recompute, give the oracle's
+    words and error counts exactly, on either wire."""
+    c, n = 2, 2048
+    x, seeds, noise, gains, weights = _cohort(c, n, 10.0)
+    wire = jnp.bfloat16 if word_bits == 16 else jnp.float32
+    x = x.astype(wire)
+    params = dict(bits_per_symbol=k, fading=fading, fade_block=64,
+                  block_words=512, word_bits=word_bits,
+                  clamp_mask=0xBFFF if word_bits == 16 else 0xBFFFFFFF)
+    refs = _ref_rows(x, seeds, noise, gains, **params)
+    if kernel == "batch":
+        rows, errs = K.approx_channel_batch_pallas(
+            x, seeds, noise, gains, interpret=True, **params)
+        for i, (want, want_errs) in enumerate(refs):
+            np.testing.assert_array_equal(
+                np.asarray(rows[i]).view(f"u{word_bits // 8}"),
+                np.asarray(want).view(f"u{word_bits // 8}"))
+            assert int(errs[i]) == int(want_errs)
+        return
+    agg, errs, exact = K.approx_channel_batch_aggregate_pallas(
+        x, seeds, noise, gains, weights, interpret=True, **params)
+    for i, (_, want_errs) in enumerate(refs):
+        assert int(errs[i]) == int(want_errs)
+    _assert_is_the_scan(agg, refs, weights)
+    assert ((np.asarray(exact) >= 0) & (np.asarray(exact) <= n // 512)).all()
+
+
+@pytest.mark.parametrize("resident", [False, True],
+                         ids=["per_tile_counter", "resident_counter"])
+def test_high_noise_takes_the_exact_path_and_keeps_the_oracles_words(
+        monkeypatch, resident):
+    """At 0 dB most tiles hold a decision near a threshold: the exact path
+    runs, is counted per client, and the words stay the oracle's."""
+    if resident:
+        monkeypatch.setattr(K, "PER_TILE_COUNTER_BYTES", 0)
+    c, n = 3, 4096
+    x, seeds, noise, gains, weights = _cohort(c, n, 0.0, seed=22)
+    params = dict(bits_per_symbol=2, fading="rayleigh", block_words=1024,
+                  clamp_mask=0xFFFFFFFF)
+    agg, errs, exact = K.approx_channel_batch_aggregate_pallas(
+        x, seeds, noise, gains, weights, interpret=True, **params)
+    refs = _ref_rows(x, seeds, noise, gains, **params)
+    for i, (_, want_errs) in enumerate(refs):
+        assert int(errs[i]) == int(want_errs)
+    _assert_is_the_scan(agg, refs, weights)
+    exact = np.asarray(exact)
+    assert exact.shape == (c,)
+    assert exact.sum() > 0 and (exact <= n // 1024).all()
+
+
+
+@pytest.mark.parametrize("kernel", ["batch", "fused"])
+def test_a_partial_last_group_keeps_the_oracles_words(kernel):
+    """A full group of ``GROUP_TILES`` tiles and a partial one of 3: the
+    partial group transmits its tiles alone, masked clients stay out, and
+    a running aggregate is added to, as the oracle's rows give."""
+    tiles = K.GROUP_TILES + 3
+    c, n, active = 3, tiles * 256, 2
+    x, seeds, noise, gains, weights = _cohort(c, n, 0.0, seed=24)
+    params = dict(bits_per_symbol=2, fading="rayleigh", block_words=256,
+                  clamp_mask=0xBFFFFFFF)
+    refs = _ref_rows(x, seeds, noise, gains, **params)
+    if kernel == "batch":
+        rows, errs = K.approx_channel_batch_pallas(
+            x, seeds, noise, gains, interpret=True, num_active=active,
+            **params)
+        for i, (want, want_errs) in enumerate(refs[:active]):
+            np.testing.assert_array_equal(np.asarray(rows[i]).view("u4"),
+                                          np.asarray(want).view("u4"))
+            assert int(errs[i]) == int(want_errs)
+        assert not np.asarray(rows[active:]).any()
+        assert not np.asarray(errs[active:]).any()
+        return
+    acc = jax.random.normal(jax.random.PRNGKey(4), (n,))
+    agg, errs, exact = K.approx_channel_batch_aggregate_pallas(
+        x, seeds, noise, gains, weights, interpret=True, num_active=active,
+        acc=acc, **params)
+    for i, (_, want_errs) in enumerate(refs[:active]):
+        assert int(errs[i]) == int(want_errs)
+    from repro.core import transport as T
+
+    rows = jnp.stack([want.astype(jnp.float32) for want, _ in refs])
+    np.testing.assert_array_equal(
+        np.asarray(agg).view(np.uint32),
+        np.asarray(T._scan_weighted_sum(rows, weights, active, acc)).view(
+            np.uint32))
+    exact = np.asarray(exact)
+    assert exact[:active].sum() > 0 and (exact <= tiles).all()
+    assert not exact[active:].any() and not np.asarray(errs[active:]).any()
+
+def test_without_noise_no_tile_takes_the_exact_path():
+    c, n = 2, 4096
+    x, seeds, _, gains, weights = _cohort(c, n, 10.0, seed=23)
+    noise = jnp.zeros((c,), jnp.float32)
+    agg, errs, exact = K.approx_channel_batch_aggregate_pallas(
+        x, seeds, noise, gains, weights, bits_per_symbol=8, interpret=True)
+    assert not np.asarray(exact).any()
+    assert not np.asarray(errs).any()
+
+
+def test_the_fast_sincos_is_within_its_bound_over_every_angle():
+    """Over all 2^24 Box-Muller angles, against float64: the fast pair is
+    within ``SINCOS_ERR`` and this platform's ``jnp.sin`` / ``jnp.cos``
+    within ``PLATFORM_SINCOS_ERR``, the two bounds the margin assumes.
+    The angle is made in the same program as the pair, as in the kernel."""
+
+    @jax.jit
+    def pairs(h):
+        ang = jnp.float32(R._TWO_PI) * R.uniform01(h)
+        return (ang, *K.sincos_2pi(ang), jnp.sin(ang), jnp.cos(ang))
+
+    worst = np.zeros(4)
+    chunk = 1 << 22  # of the 2^24 draws ``h >> 8``
+    for start in range(0, 1 << 24, chunk):
+        i = jnp.arange(start, start + chunk, dtype=jnp.int32)
+        h = jax.lax.bitcast_convert_type(i, jnp.uint32) << 8
+        ang, *got = (np.asarray(a, np.float64) for a in pairs(h))
+        true = (np.sin(ang), np.cos(ang)) * 2
+        worst = np.maximum(worst, [np.max(np.abs(g - t))
+                                   for g, t in zip(got, true)])
+    assert worst[:2].max() <= K.SINCOS_ERR
+    assert worst[2:].max() <= K.PLATFORM_SINCOS_ERR
